@@ -86,9 +86,12 @@ def _init_layer(key, cfg: ArchConfig, kind: str) -> Params:
 
 
 def _stack_layers(key, cfg: ArchConfig, kind: str, n: int) -> Params:
-    keys = split_keys(key, n)
-    layers = [_init_layer(keys[i], cfg, kind) for i in range(n)]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+    """``n`` layers' params stacked on a leading axis.  One layer is
+    traced and vmapped over the split keys — the same values as
+    initializing each layer and stacking, in one layer's program (a
+    jitted full-depth init compiles in seconds, not minutes)."""
+    keys = jax.random.split(key, n)
+    return jax.vmap(lambda k: _init_layer(k, cfg, kind))(keys)
 
 
 def init_params(cfg: ArchConfig, key) -> Params:
@@ -319,16 +322,22 @@ def forward(params, batch, cfg: ArchConfig, *, moe_impl: str = "dense",
                                   moe_impl, q_block, unroll=unroll,
                                   mlstm_chunk=mlstm_chunk,
                                   remat_policy=remat_policy)
-    logits = lm_head(params, x, cfg.norm_eps)
+    logits = lm_head(params, x, cfg)
     return logits, jnp.asarray(aux, jnp.float32)
 
 
-def lm_head(params, x, norm_eps: float) -> jax.Array:
+def lm_head(params, x, cfg: ArchConfig) -> jax.Array:
     """Final norm + vocab projection — the one LM-head implementation,
-    shared by forward, decode_step and the pipelined step."""
-    x = rms_norm(x, params["ln_f"], norm_eps)
-    return jnp.einsum("bsd,dv->bsv", x, params["head"].astype(x.dtype),
-                      preferred_element_type=jnp.float32)
+    shared by forward, decode_step and the pipelined step.  Columns past
+    ``cfg.vocab_size`` (the padding to ``vocab_padded``) are masked to
+    -inf, so no sampler or argmax can emit a token outside the vocab."""
+    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+    logits = jnp.einsum("bsd,dv->bsv", x, params["head"].astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+    if cfg.vocab_padded > cfg.vocab_size:
+        real = jnp.arange(logits.shape[-1]) < cfg.vocab_size
+        logits = jnp.where(real, logits, -jnp.inf)
+    return logits
 
 
 def token_ce_loss(logits, tokens, aux=0.0) -> jax.Array:
@@ -502,8 +511,8 @@ def paged_decode_step(params, kv, block_tbl, pos, tokens, n_new,
 
     ``attn_impl`` picks the attention backend
     (``attn_backend.resolve``: ``'jnp'`` | ``'pallas'`` | ``'auto'``);
-    it is resolved once here, outside the scan, and never changes the
-    token stream (backends are gated bit-identical).
+    it is resolved once here, outside the scan (backends agree to the
+    tolerance stated in ``nn.attn_backend``).
 
     ``all_positions=True`` skips the last-valid-position narrowing and
     projects every chunk position through the head: logits (or greedy
@@ -547,7 +556,7 @@ def paged_decode_step(params, kv, block_tbl, pos, tokens, n_new,
     x, new_kv = jax.lax.scan(
         body, x, (params["layers"], kv, windows), unroll=unroll)
     if all_positions:
-        logits = lm_head(params, x, cfg.norm_eps)  # [B, C, Vp]
+        logits = lm_head(params, x, cfg)  # [B, C, Vp]
         if sample_greedy:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_kv
         return logits, new_kv
@@ -557,7 +566,7 @@ def paged_decode_step(params, kv, block_tbl, pos, tokens, n_new,
     # per-position, so this is bit-identical to projecting all C)
     last = jnp.clip(n_new - 1, 0, C - 1)
     x = jnp.take_along_axis(x, last[:, None, None], axis=1)
-    logits = lm_head(params, x, cfg.norm_eps)[:, 0]
+    logits = lm_head(params, x, cfg)[:, 0]
     if sample_greedy:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_kv
     return logits, new_kv
@@ -699,7 +708,7 @@ def decode_step(params, state, tokens, cfg: ArchConfig, *,
             new_state[key] = nc
             x, _ = _ffn(params["tail"][i], cfg, x, moe_impl)
 
-    logits = lm_head(params, x, cfg.norm_eps)[:, 0]
+    logits = lm_head(params, x, cfg)[:, 0]
     if sample_greedy:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_state
     return logits, new_state

@@ -118,7 +118,7 @@ def make_pipeline_step(cfg: ArchConfig, mesh, pspecs, *,
 
     def lm_loss(staged, h_aux, tokens):
         h, aux = h_aux
-        logits = M.lm_head(staged, h, cfg.norm_eps)
+        logits = M.lm_head(staged, h, cfg)
         return M.token_ce_loss(logits, tokens, aux)
 
     def pipeline_loss(staged, batch):

@@ -13,7 +13,7 @@ Kernel index:
   maps, fusing gather + int8 dequant + masked softmax attention in one
   launch (decode ``C=1`` and prefill-chunk ``[B, C]`` variants).  Its
   oracle is the registered ``"jnp"`` backend in
-  ``repro.nn.attn_backend`` (gated bitwise-identical); selected via
+  ``repro.nn.attn_backend`` (matched to a stated tolerance); selected via
   ``ServeConfig(attn_impl=...)`` / ``--attn-impl``.
 """
 from .ops import (

@@ -23,8 +23,12 @@ from .fused_eb import fused_eb_pallas
 from .lb_lookup import lb_lookup_pallas
 from .ternary_match import ternary_match_pallas
 
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-_INTERPRET = not _ON_TPU
+
+def _interpret() -> bool:
+    """Pallas interpret mode off-TPU, decided at call time (importing
+    this module touches no device)."""
+    return jax.default_backend() != "tpu"
+
 
 __all__ = [
     "bucketize",
@@ -41,7 +45,7 @@ def bucketize(values, thresholds, backend: str = "jnp"):
     values = jnp.asarray(values, jnp.int32)
     thresholds = jnp.asarray(thresholds, jnp.int32)
     if backend == "pallas":
-        return bucketize_pallas(values, thresholds, interpret=_INTERPRET)
+        return bucketize_pallas(values, thresholds, interpret=_interpret())
     return ref.bucketize_ref(values, thresholds)
 
 
@@ -56,7 +60,7 @@ def ternary_match(keys, values, masks, prio_action, default_action: int,
     if backend == "pallas":
         return ternary_match_pallas(
             keys, values, masks, prio_action,
-            default_action=int(default_action), interpret=_INTERPRET,
+            default_action=int(default_action), interpret=_interpret(),
         )
     return ref.ternary_match_ref(keys, values, masks, prio_action,
                                  int(default_action))
@@ -66,7 +70,7 @@ def lb_lookup(codes, luts, backend: str = "jnp", action_bits: int = 16):
     codes = jnp.asarray(codes, jnp.int32)
     luts = jnp.asarray(luts, jnp.int32)
     if backend == "pallas" and action_bits <= 16:
-        return lb_lookup_pallas(codes, luts, interpret=_INTERPRET)
+        return lb_lookup_pallas(codes, luts, interpret=_interpret())
     return ref.lb_lookup_ref(codes, luts)
 
 
@@ -75,7 +79,7 @@ def bnn_popcount_matmul(x_packed, w_packed, backend: str = "jnp"):
     w_packed = jnp.asarray(w_packed, jnp.uint32)
     if backend == "pallas":
         return bnn_popcount_matmul_pallas(x_packed, w_packed,
-                                          interpret=_INTERPRET)
+                                          interpret=_interpret())
     return ref.bnn_popcount_matmul_ref(x_packed, w_packed)
 
 
@@ -94,7 +98,7 @@ def fused_eb_match(values, thresholds, rows_v, rows_m, prio_action,
             jnp.asarray(rows_v, jnp.uint32), jnp.asarray(rows_m, jnp.uint32),
             jnp.asarray(prio_action, jnp.int32), layout=tuple(layout),
             n_words=int(n_words), default_action=int(default_action),
-            block_b=int(block_b), interpret=_INTERPRET, identity=identity)
+            block_b=int(block_b), interpret=_interpret(), identity=identity)
     # jnp composition fallback (same semantics, two ops)
     codes = (jnp.asarray(values, jnp.int32) if identity else
              ref.bucketize_ref(jnp.asarray(values, jnp.int32),

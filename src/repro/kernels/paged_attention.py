@@ -6,7 +6,7 @@ HBM every layer of every decode step: ``k_pages[block_tbl]`` writes a
 adds a dequant round trip, and ``repeat_kv`` multiplies the read
 traffic by ``H/KV`` for GQA stacks.  For decode (1 query token) that
 gather traffic *is* the roofline — see ``benchmarks/roofline.py
---paged-attn`` for the measured bytes.
+--paged-attn`` for the computed bytes.
 
 This kernel fuses the whole read side into one launch.  A scalar-
 prefetch grid ``(B, n_ps)`` walks each slot's block table page by
@@ -14,26 +14,24 @@ page: the prefetched (clipped) table drives the K/V ``BlockSpec``
 index maps, so each physical page is DMA'd HBM->VMEM exactly once, at
 pool dtype, dequantized (int8 pools: per-page f32 scale planes ride
 along and the multiply happens in registers) and staged into a
-VMEM-resident logical view; the final grid step over a slot runs
-masking + softmax + the value einsum entirely out of VMEM.  Nothing
-per-``S`` ever touches HBM: no gathered view, no dequantized copy, no
-``H/KV``-repeated K/V — HBM cost per slot is ``n_ps*page*KV*hd`` pool
-bytes (+ scale planes) plus q/out.
+per-slot VMEM view ``[S, KV*hd]``.  The last page step of a slot runs
+masking + softmax + the value matmul for that slot out of VMEM, one
+KV head at a time against its ``H/KV`` query heads (no repeated K/V).
+VMEM holds one slot's view, ``2*S*KV*hd`` elements, whatever the batch.
 
-Deliberate deviation from flash-style *online* softmax: the softmax
-runs full-axis over the VMEM-staged view, with bitwise the same
-operations as the jnp oracle.  Online rescaling re-associates the
-reduction and cannot be bit-exact, and this repo's serving contract is
-bit-exactness (token streams are hard-gated identical across batchers,
-meshes, chunk widths and now backends).  HBM traffic is identical
-either way — each pool page is read once — what online softmax would
-buy is O(page) instead of O(S) VMEM residency, which matters only past
-``S*KV*hd ~ 1M`` elements; revisit when contexts outgrow VMEM.
+The softmax runs full-axis over the staged view, with the same
+operations as the jnp oracle; only the matmul shapes differ (one slot
+and one KV group per call, f32 accumulation as the TPU requires), so
+the f32 sums may be ordered differently.  The kernel agrees with the
+oracle to a stated tolerance: a few f32 ulps in interpret mode
+(``tests/test_kernels.py``), about one bf16 step per call compiled for
+the TPU (``chip_smoke.py``).  Online
+softmax, which would bound VMEM at ``O(page)``, is later work.
 
-Masking is ``attn_backend.position_mask`` on per-slot absolute
-positions — the *same helper object* the jnp oracle and the dense
-decode path call — so page-boundary behaviour cannot drift between
-implementations.
+Masking goes through ``attn_backend.mask_from_diff`` — the helper the
+jnp oracle and the dense decode path reach through ``position_mask`` —
+on per-slot absolute positions, so page-boundary behaviour cannot drift
+between implementations.
 
 Decode is the ``C=1`` case of the prefill-chunk ``[B, C]`` variant;
 one kernel serves both (the chunk width only changes block shapes).
@@ -53,54 +51,68 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..nn.attn_backend import position_mask, repeat_kv
+from ..nn.attn_backend import mask_from_diff
 
 __all__ = ["paged_attention", "paged_attention_hbm_bytes"]
 
 
-def _kernel(n_batch: int, n_ps: int, page: int, n_heads: int,
-            quantized: bool, out_dtype, tbl_ref, pos_ref, win_ref, q_ref,
-            kp_ref, vp_ref, *rest):
+def _kernel(n_ps: int, page: int, n_kv: int, hd: int, group: int,
+            n_chunk: int, quantized: bool, out_dtype, tbl_ref, pos_ref,
+            win_ref, q_ref, kp_ref, vp_ref, *rest):
     """One grid step ``(b, s)``: stage slot b's logical page s into the
-    batch-wide VMEM view; on the last grid step, attend over all slots.
+    slot's VMEM view; on the slot's last page, attend for slot b.
 
-    ``tbl_ref``/``pos_ref``/``win_ref`` are scalar-prefetch operands
-    (the clipped block table also drives the K/V BlockSpec index maps,
-    which is what makes the gather a sequence of page DMAs instead of
-    an HBM materialization).  The attend runs *once*, over the full
-    ``[B, S]`` staged view, so its einsums/softmax see exactly the
-    shapes the jnp oracle lowers — per-slot attends would hit
-    shape-dependent reduction blocking and drift by ulps, breaking the
-    bitwise contract."""
+    ``tbl_ref``/``pos_ref``/``win_ref`` are scalar-prefetch operands in
+    SMEM, read one scalar at a time (the flattened clipped block table
+    also drives the K/V BlockSpec index maps, which is what makes the
+    gather a sequence of page DMAs instead of an HBM materialization).
+    ``q_ref`` is slot b's queries grouped by KV head,
+    ``[1, KV, C*group, hd]`` with row ``c*group + g``."""
+    del tbl_ref
     if quantized:
         ks_ref, vs_ref, out_ref, kg, vg = rest
     else:
         out_ref, kg, vg = rest
     b = pl.program_id(0)
     s = pl.program_id(1)
-    sl = pl.ds(s * page, page)
+    f32 = jnp.float32
+    rows = pl.ds(pl.multiple_of(s * page, page), page)
     if quantized:
-        # dequant in-flight: int8 page * f32 scale plane -> compute dtype
-        kg[b, sl] = kp_ref[0].astype(out_dtype) * ks_ref[0].astype(out_dtype)
-        vg[b, sl] = vp_ref[0].astype(out_dtype) * vs_ref[0].astype(out_dtype)
+        # dequant in-flight: int8 page * scale plane, one rounding to the
+        # compute dtype (the product of two bf16 values is exact in f32,
+        # so this equals the oracle's multiply in the compute dtype)
+        for h in range(n_kv):
+            cols = pl.ds(h * hd, hd)
+            for src, scl, dst in ((kp_ref, ks_ref, kg), (vp_ref, vs_ref, vg)):
+                sc = scl[0, :, pl.ds(h, 1)].astype(out_dtype).astype(f32)
+                dst[rows, cols] = (src[0, :, cols].astype(f32)
+                                   * sc).astype(out_dtype)
     else:
-        kg[b, sl] = kp_ref[0].astype(out_dtype)
-        vg[b, sl] = vp_ref[0].astype(out_dtype)
+        kg[rows, :] = kp_ref[0].astype(out_dtype)
+        vg[rows, :] = vp_ref[0].astype(out_dtype)
 
-    @pl.when((b == n_batch - 1) & (s == n_ps - 1))
-    def _attend():  # VMEM view complete — same ops/shapes as the oracle
-        B, S = n_batch, n_ps * page
-        hd = q_ref.shape[-1]
-        qb = q_ref[...]
-        # scratch Refs must be loaded before use in jnp ops
-        kf = repeat_kv(kg[...], n_heads)
-        vf = repeat_kv(vg[...], n_heads)
-        k_pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-        mask = position_mask(pos_ref[...], k_pos, win_ref[0], causal=True)
-        sc = jnp.einsum("bqhd,bshd->bhqs", qb, kf) / np.sqrt(hd)
-        sc = sc.astype(jnp.float32) + mask[:, None, :, :]
-        probs = jax.nn.softmax(sc, axis=-1).astype(out_dtype)
-        out_ref[...] = jnp.einsum("bhqs,bshd->bqhd", probs, vf)
+    @pl.when(s == n_ps - 1)
+    def _attend():
+        n_rows, S = n_chunk * group, n_ps * page
+        shape = (n_rows, S)
+        r = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        q_pos = jnp.full(shape, pos_ref[b * n_chunk], jnp.int32)
+        for c in range(1, n_chunk):
+            q_pos = jnp.where(r >= c * group, pos_ref[b * n_chunk + c], q_pos)
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        mask = mask_from_diff(q_pos - k_pos, win_ref[0], causal=True)
+        scale = np.float32(np.sqrt(hd))
+        for h in range(n_kv):
+            cols = pl.ds(h * hd, hd)
+            sc = jax.lax.dot_general(
+                q_ref[0, h], kg[:, cols], (((1,), (1,)), ((), ())),
+                preferred_element_type=f32)
+            sc = sc.astype(out_dtype).astype(f32) / scale + mask
+            probs = jax.nn.softmax(sc, axis=-1).astype(out_dtype)
+            out = jax.lax.dot_general(
+                probs, vg[:, cols], (((1,), (0,)), ((), ())),
+                preferred_element_type=f32)
+            out_ref[0, h] = out.astype(out_dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -110,8 +122,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     v_scale: Optional[jax.Array] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Attend ``q [B, C, H, hd]`` over a paged pool through its block
-    table.  Bitwise-identical to the registered ``"jnp"`` backend on
-    the same operands (asserted in ``tests/test_kernels.py``).
+    table.  Matches the registered ``"jnp"`` backend on the same
+    operands to the tolerance stated in the module docstring.
 
     Args:
       q: projected queries, rope applied, ``[B, C, H, hd]`` (``C=1``
@@ -133,48 +145,53 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     B, C, H, hd = q.shape
     N_pages, page, KV, _ = k_pages.shape
     n_ps = block_tbl.shape[1]
-    S = n_ps * page
+    group = H // KV
     dt = q.dtype
     quantized = k_scale is not None
 
-    gtbl = jnp.clip(block_tbl, 0, N_pages - 1).astype(jnp.int32)
-    pos = positions.astype(jnp.int32)
+    gtbl = jnp.clip(block_tbl, 0, N_pages - 1).astype(jnp.int32).reshape(-1)
+    pos = positions.astype(jnp.int32).reshape(-1)
     win = jnp.asarray(window, jnp.int32).reshape(1)
+    # query head kv*group + g (repeat_kv's order) -> [B, KV, C*group, hd]
+    qg = q.reshape(B, C, KV, group, hd).transpose(0, 2, 1, 3, 4).reshape(
+        B, KV, C * group, hd)
 
     def page_map(b, s, tbl, *_):
-        return (tbl[b, s], 0, 0, 0)
+        return (tbl[b * n_ps + s], 0, 0)
 
-    def whole_map(b, s, *_):
-        return (0, 0, 0, 0)
+    def slot_map(b, s, *_):
+        return (b, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((B, C, H, hd), whole_map),           # q
-        pl.BlockSpec((1, page, KV, hd), page_map),        # k_pages
-        pl.BlockSpec((1, page, KV, hd), page_map),        # v_pages
+        pl.BlockSpec((1, KV, C * group, hd), slot_map),   # q
+        pl.BlockSpec((1, page, KV * hd), page_map),       # k_pages
+        pl.BlockSpec((1, page, KV * hd), page_map),       # v_pages
     ]
-    operands = [q, k_pages, v_pages]
+    operands = [qg, k_pages.reshape(N_pages, page, KV * hd),
+                v_pages.reshape(N_pages, page, KV * hd)]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, page, KV, 1), page_map),     # k_scale
-            pl.BlockSpec((1, page, KV, 1), page_map),     # v_scale
-        ]
-        operands += [k_scale, v_scale]
+        in_specs += [pl.BlockSpec((1, page, KV), page_map)] * 2
+        operands += [k_scale.reshape(N_pages, page, KV),
+                     v_scale.reshape(N_pages, page, KV)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # gtbl, pos, win
         grid=(B, n_ps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((B, C, H, hd), whole_map),
-        scratch_shapes=[pltpu.VMEM((B, S, KV, hd), dt),   # staged K view
-                        pltpu.VMEM((B, S, KV, hd), dt)],  # staged V view
+        out_specs=pl.BlockSpec((1, KV, C * group, hd), slot_map),
+        scratch_shapes=[pltpu.VMEM((n_ps * page, KV * hd), dt),  # slot K
+                        pltpu.VMEM((n_ps * page, KV * hd), dt)],  # slot V
     )
-    kern = functools.partial(_kernel, B, n_ps, page, H, quantized, dt)
-    return pl.pallas_call(
+    kern = functools.partial(_kernel, n_ps, page, KV, hd, group, C,
+                             quantized, dt)
+    out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, H, hd), dt),
+        out_shape=jax.ShapeDtypeStruct((B, KV, C * group, hd), dt),
         interpret=interpret,
     )(gtbl, pos, win, *operands)
+    return out.reshape(B, KV, C, group, hd).transpose(0, 2, 1, 3, 4).reshape(
+        B, C, H, hd)
 
 
 def paged_attention_hbm_bytes(B: int, C: int, H: int, KV: int, hd: int,

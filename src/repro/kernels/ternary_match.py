@@ -9,7 +9,8 @@ priority-max on the VPU (DESIGN.md §2, row 3).
 
 Grid: ``(batch_blocks, row_blocks)``; rows iterate fastest (TPU minor grid
 axis), the scratch carries the per-batch running best across row blocks,
-and the output is emitted on the last row block.
+and the output is emitted on the last row block.  Keys enter transposed
+(``[W, B]``) so the batch rides the lanes and every block is 2-D.
 """
 from __future__ import annotations
 
@@ -19,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .fused_eb import match_rows, as_int32_bits
 
 DEFAULT_BLOCK_B = 256
 DEFAULT_BLOCK_N = 512
@@ -32,14 +35,10 @@ def _ternary_kernel(keys_ref, values_ref, masks_ref, pa_ref, out_ref, best_ref):
     def _init():
         best_ref[...] = jnp.full_like(best_ref, -1)
 
-    k = keys_ref[...]  # [Bb, W] uint32
-    v = values_ref[...]  # [Nb, W] uint32
-    m = masks_ref[...]  # [Nb, W] uint32
-    pa = pa_ref[...]  # [Nb] int32 (prio*256 + action; -1 = padding row)
-
-    hit = jnp.all((k[:, None, :] & m[None, :, :]) == v[None, :, :], axis=-1)
-    score = jnp.where(hit, pa[None, :], -1)  # [Bb, Nb]
-    blk_best = score.max(axis=1)  # [Bb]
+    k = keys_ref[...]  # [W, Bb] int32 key bits (batch on lanes)
+    blk_best = match_rows([k[w:w + 1] for w in range(k.shape[0])],
+                           values_ref[...], masks_ref[...],
+                           pa_ref[...])  # [1, Bb]
     best_ref[...] = jnp.maximum(best_ref[...], blk_best)
 
     @pl.when(n_idx == n_blocks - 1)
@@ -66,8 +65,7 @@ def ternary_match_pallas(
     N = values.shape[0]
     pad_b = (-B) % block_b
     pad_n = (-N) % block_n
-    if pad_b:
-        keys = jnp.pad(keys, ((0, pad_b), (0, 0)))
+    keys_t = as_int32_bits(jnp.pad(keys, ((0, pad_b), (0, 0)))).T
     if pad_n:
         # padding rows: mask=all-ones, value=all-ones -> never match a real
         # key unless key is all-ones AND... make them unmatchable by giving
@@ -81,15 +79,16 @@ def ternary_match_pallas(
         _ternary_kernel,
         grid=(Bp // block_b, Np // block_n),
         in_specs=[
-            pl.BlockSpec((block_b, W), lambda i, j: (i, 0)),
+            pl.BlockSpec((W, block_b), lambda i, j: (0, i)),
             pl.BlockSpec((block_n, W), lambda i, j: (j, 0)),
             pl.BlockSpec((block_n, W), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_n,), lambda i, j: (j,)),
+            pl.BlockSpec((block_n, 1), lambda i, j: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((block_b,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Bp,), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((block_b,), jnp.int32)],
+        out_specs=pl.BlockSpec((1, block_b), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, Bp), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, block_b), jnp.int32)],
         interpret=interpret,
-    )(keys, values, masks, prio_action)
-    best = best[:B]
+    )(keys_t, as_int32_bits(values), as_int32_bits(masks),
+      prio_action.reshape(Np, 1))
+    best = best[0, :B]
     return jnp.where(best >= 0, best % 256, default_action).astype(jnp.int32)

@@ -35,6 +35,20 @@ from ..serve.engine import (ContinuousBatcher, DeviceContinuousBatcher,
                             ServeConfig, ServeEngine)
 from ..serve.router import ShardedServe
 
+# prompt length of the one-batch generate() path (no --continuous)
+GENERATE_PROMPT_LEN = 4
+
+
+def derived_cache_len(prompt_len: int, shared_prefix_len: int,
+                      tokens: int, page_size: int) -> int:
+    """Cache length that holds the longest request this run submits:
+    shared prefix + prompt + generated tokens, rounded up to whole
+    pages (the one-batch path seeds ``GENERATE_PROMPT_LEN`` tokens)."""
+    need = (shared_prefix_len + max(prompt_len, GENERATE_PROMPT_LEN)
+            + tokens)
+    page = max(1, page_size)
+    return -(-need // page) * page
+
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
@@ -85,8 +99,7 @@ def main(argv=None):
                          "registry): auto = Pallas page-walking kernel "
                          "on TPU / jnp gather oracle elsewhere; "
                          "'pallas' off-TPU runs the kernel in interpret "
-                         "mode (slow, correctness checks only).  Token "
-                         "streams are bit-identical across backends")
+                         "mode (slow, correctness checks only)")
     ap.add_argument("--shared-prefix-len", type=int, default=0,
                     help="workload: prepend this many common prefix "
                          "tokens to every prompt (exercises "
@@ -163,7 +176,8 @@ def main(argv=None):
                          "fresh batcher")
     ap.add_argument("--jax-profile", default=None, metavar="DIR",
                     help="capture a jax.profiler trace of the serve run "
-                         "into DIR (view with TensorBoard); pair with "
+                         "into DIR (view with TensorBoard); the run fails "
+                         "if the profiler cannot start; pair with "
                          "XLA_FLAGS=--xla_step_marker_location=1 to mark "
                          "fused-step boundaries")
     ap.add_argument("--seed", type=int, default=0)
@@ -208,7 +222,9 @@ def main(argv=None):
                      "schedule replay assumes one token per step)")
     if (args.top_k or args.top_p < 1.0) and args.temperature == 0.0:
         ap.error("--top-k/--top-p need --temperature > 0")
-    scfg = ServeConfig(max_batch=args.batch, cache_len=64,
+    cache_len = derived_cache_len(args.prompt_len, args.shared_prefix_len,
+                                  args.tokens, args.page_size)
+    scfg = ServeConfig(max_batch=args.batch, cache_len=cache_len,
                        page_size=args.page_size, pages=args.pages,
                        share_prefix=args.share_prefix,
                        kv_int8=args.kv_int8, attn_impl=args.attn_impl,
@@ -226,13 +242,9 @@ def main(argv=None):
         from ..obs import Metrics, Tracer
         metrics = Metrics()
         tracer = Tracer(metrics=metrics)
-    profiling = False
-    if args.jax_profile:
-        try:
-            jax.profiler.start_trace(args.jax_profile)
-            profiling = True
-        except Exception as e:  # profiler backend unavailable: still serve
-            print(f"jax-profile disabled ({e})")
+    profiling = bool(args.jax_profile)
+    if profiling:
+        jax.profiler.start_trace(args.jax_profile)
     injector = None
     if args.fault_plan:
         from ..serve.faults import FaultPlan
@@ -401,14 +413,17 @@ def main(argv=None):
           f"(dropped {100 * (1 - keep.mean()):.1f}% as attack traffic)")
 
     admitted = np.where(keep)[0][: args.batch]
-    prompts = rng.integers(0, cfg.vocab_size, (args.batch, 4))
+    prompts = rng.integers(0, cfg.vocab_size,
+                           (args.batch, GENERATE_PROMPT_LEN))
     t0 = time.perf_counter()
     out = engine.generate(prompts, args.tokens,
                           features=feats[: args.batch])
     dt = time.perf_counter() - t0
     n_tok = out.size
+    dev = jax.devices()[0]
     print(f"generated {n_tok} tokens in {dt:.2f}s "
-          f"({n_tok / dt:.1f} tok/s on CPU smoke config)")
+          f"({n_tok / dt:.1f} tok/s on {dev.platform} {dev.device_kind}, "
+          f"{cfg.name})")
     print("sample:", out[0][:8])
     if profiling:
         jax.profiler.stop_trace()
@@ -421,4 +436,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -127,13 +127,9 @@ def main(argv=None):
         from ..dist.compress import compression_ratio
         metrics.gauge("train.compression_ratio").set(
             compression_ratio(params))
-    profiling = False
-    if args.jax_profile:
-        try:
-            jax.profiler.start_trace(args.jax_profile)
-            profiling = True
-        except Exception as e:
-            print(f"jax-profile disabled ({e})")
+    profiling = bool(args.jax_profile)
+    if profiling:
+        jax.profiler.start_trace(args.jax_profile)
 
     def do_ckpt():
         if mgr is not None:
@@ -253,4 +249,6 @@ def _run_elastic(args, cfg, tcfg, pipe):
 
 
 if __name__ == "__main__":
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -252,9 +252,8 @@ def paged_decode_attention_block(
     ``'jnp'`` gather oracle, ``'pallas'`` page-walking kernel,
     ``'auto'`` = platform default).  The projection and the page write
     run *outside* the backend, so the returned pools are bitwise
-    identical across impls, and registered backends are gated
-    bit-identical on fp pools — token streams do not depend on the
-    backend choice.
+    identical across impls; the attention outputs agree to the
+    tolerance stated in ``attn_backend``.
 
     Bit-exactness contract: for a chunk of width 1 starting at the same
     position, the gathered axis has the same length, values and mask as

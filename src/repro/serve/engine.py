@@ -114,8 +114,8 @@ class ServeConfig:
     # paged-attention backend (repro.nn.attn_backend registry):
     # 'auto' = Pallas kernel on TPU / jnp gather oracle elsewhere;
     # explicit 'jnp' | 'pallas' force one (the kernel runs in interpret
-    # mode off-TPU — slow, correctness-leg only).  Never changes token
-    # streams: backends are hard-gated bit-identical.
+    # mode off-TPU — slow, correctness-leg only).  Backends agree to the
+    # tolerance stated in nn.attn_backend.
     attn_impl: str = "auto"
     # on-device sampling (arch.sampling): STATIC python scalars, so
     # temperature=0.0 compiles to exactly the seed argmax (greedy stays
@@ -1096,6 +1096,13 @@ class DeviceContinuousBatcher:
             request_id, prompt,
             None if features is None else np.asarray(features)))
         return True
+
+    @property
+    def kv_pages(self):
+        """The device page pool (the KV leaves of the donated slot
+        pytree, as the last ``run()`` left them); None on the dense
+        path."""
+        return self._pages if self.paged else None
 
     def pending_work(self) -> int:
         """Un-served load: queued entries + backed-off retries +

@@ -110,14 +110,27 @@ def stable_shard(request_id: Any, n_shards: int) -> int:
     return rendezvous_shard(request_id, range(n_shards))
 
 
+def sharded_gate(gate_fn: Callable, mesh, spec) -> Callable:
+    """One gate launch over a mesh whose feature rows are placed with
+    ``spec``: each device gates its own rows.  The compiler cannot
+    partition a Pallas gate kernel, so the split is an explicit
+    ``shard_map`` (rows are independent; verdicts come back split the
+    same way)."""
+    from jax.sharding import PartitionSpec
+
+    return jax.jit(jax.shard_map(gate_fn, mesh=mesh, in_specs=spec,
+                                 out_specs=PartitionSpec(spec[0]),
+                                 check_vma=False))
+
+
 class ShardedServe:
     """Data-parallel serve shards behind one submit/run interface.
 
     Engine-level knobs ride in on ``scfg`` — notably
     ``ServeConfig(attn_impl=...)`` (the paged-attention backend from
     ``repro.nn.attn_backend``), which every shard's engine picks up
-    identically; backends are bit-identical, so routing and failover
-    replay are backend-agnostic.
+    identically, so every shard and every failover replay attends
+    with the same backend.
     """
 
     def __init__(self, cfg, params, scfg: ServeConfig, mesh, *,
@@ -169,6 +182,7 @@ class ShardedServe:
                                     spec_k=spec_k, draft=draft)
             for eng in self.engines]
         self._gate_fn = self.engines[0].gate_fn
+        self._admit_fns: dict = {}  # queue PartitionSpec -> gate launch
         self._drop = scfg.gate_action_drop
         self._scfg = scfg
         self.max_tokens = int(max_tokens)
@@ -231,11 +245,13 @@ class ShardedServe:
                 self._gate_fn(jnp.asarray(features))) != self._drop
         from jax.sharding import NamedSharding
 
-        x = jax.device_put(
-            jnp.asarray(features),
-            NamedSharding(self.mesh,
-                          SH.queue_pspec(self.mesh, len(features), 2)))
-        return np.asarray(self._gate_fn(x)) != self._drop
+        spec = SH.queue_pspec(self.mesh, len(features), 2)
+        if spec not in self._admit_fns:
+            self._admit_fns[spec] = sharded_gate(self._gate_fn, self.mesh,
+                                                 spec)
+        x = jax.device_put(jnp.asarray(features),
+                           NamedSharding(self.mesh, spec))
+        return np.asarray(self._admit_fns[spec](x)) != self._drop
 
     # -------------------------------------------------------------- routing
     def submit(self, request_id, prompt_tokens,

@@ -21,17 +21,10 @@ Both allocation protocols are exercised: the host batcher's atomic
 ``register_completed`` at drain).  A third, model-backed test drives
 ``DeviceContinuousBatcher`` itself through random bounded ``run()``
 calls (the resume path) and checks the pool after every wave.
-
-Falls back to the deterministic shim in ``_hypothesis_fallback`` when
-hypothesis isn't installed (the CI container has no network installs).
 """
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.serve.pages import PagePool, page_demand
 

@@ -1,14 +1,6 @@
-"""Hypothesis property tests on the system's table invariants.
-
-Falls back to the deterministic shim in ``_hypothesis_fallback`` when
-hypothesis isn't installed (the CI container has no network installs).
-"""
+"""Hypothesis property tests on the system's table invariants."""
 import numpy as np
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.tables import (pack_codes, range_to_ternary)
 from repro.core import encode_based as EB
