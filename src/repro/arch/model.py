@@ -130,13 +130,16 @@ def init_params(cfg: ArchConfig, key) -> Params:
 def _ffn(p: Params, cfg: ArchConfig, x, moe_impl: str):
     if cfg.d_ff == 0:
         return x, 0.0
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.n_experts:
-        fn = moe_block_sparse if moe_impl == "sparse" else moe_block
-        out, aux = fn(p["moe"], h, n_experts=cfg.n_experts,
-                      top_k=cfg.n_experts_active, act=cfg.act)
-        return x + out, aux
-    return x + mlp_block(p["mlp"], h, cfg.act), 0.0
+    # named scope: the profiler's name stack of every op below (the
+    # benchmark's mlp_share / moe_share read it)
+    with jax.named_scope("moe" if cfg.n_experts else "mlp"):
+        h = rms_norm(x, p["ln2"], cfg.norm_eps)
+        if cfg.n_experts:
+            fn = moe_block_sparse if moe_impl == "sparse" else moe_block
+            out, aux = fn(p["moe"], h, n_experts=cfg.n_experts,
+                          top_k=cfg.n_experts_active, act=cfg.act)
+            return x + out, aux
+        return x + mlp_block(p["mlp"], h, cfg.act), 0.0
 
 
 def _mixer_fwd(p: Params, cfg: ArchConfig, kind: str, x, window,
@@ -331,13 +334,21 @@ def lm_head(params, x, cfg: ArchConfig) -> jax.Array:
     shared by forward, decode_step and the pipelined step.  Columns past
     ``cfg.vocab_size`` (the padding to ``vocab_padded``) are masked to
     -inf, so no sampler or argmax can emit a token outside the vocab."""
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["head"].astype(x.dtype),
-                        preferred_element_type=jnp.float32)
-    if cfg.vocab_padded > cfg.vocab_size:
-        real = jnp.arange(logits.shape[-1]) < cfg.vocab_size
-        logits = jnp.where(real, logits, -jnp.inf)
-    return logits
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        logits = jnp.einsum("bsd,dv->bsv", x,
+                            params["head"].astype(x.dtype),
+                            preferred_element_type=jnp.float32)
+        if cfg.vocab_padded > cfg.vocab_size:
+            real = jnp.arange(logits.shape[-1]) < cfg.vocab_size
+            logits = jnp.where(real, logits, -jnp.inf)
+        return logits
+
+
+def greedy(logits) -> jax.Array:
+    """Greedy sampling: the argmax token id (int32) over the last axis."""
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def token_ce_loss(logits, tokens, aux=0.0) -> jax.Array:
@@ -558,7 +569,7 @@ def paged_decode_step(params, kv, block_tbl, pos, tokens, n_new,
     if all_positions:
         logits = lm_head(params, x, cfg)  # [B, C, Vp]
         if sample_greedy:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_kv
+            return greedy(logits), new_kv
         return logits, new_kv
     # select each slot's last valid position BEFORE the vocab
     # projection: the head is the dominant decode matmul and only one
@@ -568,7 +579,7 @@ def paged_decode_step(params, kv, block_tbl, pos, tokens, n_new,
     x = jnp.take_along_axis(x, last[:, None, None], axis=1)
     logits = lm_head(params, x, cfg)[:, 0]
     if sample_greedy:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_kv
+        return greedy(logits), new_kv
     return logits, new_kv
 
 
@@ -710,5 +721,5 @@ def decode_step(params, state, tokens, cfg: ArchConfig, *,
 
     logits = lm_head(params, x, cfg)[:, 0]
     if sample_greedy:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_state
+        return greedy(logits), new_state
     return logits, new_state

@@ -167,12 +167,15 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         pl.BlockSpec((1, page, KV * hd), page_map),       # k_pages
         pl.BlockSpec((1, page, KV * hd), page_map),       # v_pages
     ]
-    operands = [qg, k_pages.reshape(N_pages, page, KV * hd),
-                v_pages.reshape(N_pages, page, KV * hd)]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, page, KV), page_map)] * 2
-        operands += [k_scale.reshape(N_pages, page, KV),
-                     v_scale.reshape(N_pages, page, KV)]
+    # the pool's hand-off to the kernel (a relayout on the TPU) is
+    # traced under the ``kv`` scope, with the page write
+    with jax.named_scope("kv"):
+        operands = [qg, k_pages.reshape(N_pages, page, KV * hd),
+                    v_pages.reshape(N_pages, page, KV * hd)]
+        if quantized:
+            in_specs += [pl.BlockSpec((1, page, KV), page_map)] * 2
+            operands += [k_scale.reshape(N_pages, page, KV),
+                         v_scale.reshape(N_pages, page, KV)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # gtbl, pos, win

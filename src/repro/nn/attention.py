@@ -194,23 +194,24 @@ def _paged_write(kv: PagedKV, k: jax.Array, v: jax.Array) -> PagedKV:
     The int8 pool quantizes per token vector and scatters the f32
     scale planes alongside the values.
     """
-    if kv.quantized:
-        kq, ks = quantize_kv_int8(k)
-        vq, vs = quantize_kv_int8(v)
+    with jax.named_scope("kv"):
+        if kv.quantized:
+            kq, ks = quantize_kv_int8(k)
+            vq, vs = quantize_kv_int8(v)
+            return dataclasses.replace(
+                kv,
+                k=kv.k.at[kv.page_ids, kv.page_off].set(kq, mode="drop"),
+                v=kv.v.at[kv.page_ids, kv.page_off].set(vq, mode="drop"),
+                k_scale=kv.k_scale.at[kv.page_ids, kv.page_off].set(
+                    ks.astype(kv.k_scale.dtype), mode="drop"),
+                v_scale=kv.v_scale.at[kv.page_ids, kv.page_off].set(
+                    vs.astype(kv.v_scale.dtype), mode="drop"))
         return dataclasses.replace(
             kv,
-            k=kv.k.at[kv.page_ids, kv.page_off].set(kq, mode="drop"),
-            v=kv.v.at[kv.page_ids, kv.page_off].set(vq, mode="drop"),
-            k_scale=kv.k_scale.at[kv.page_ids, kv.page_off].set(
-                ks.astype(kv.k_scale.dtype), mode="drop"),
-            v_scale=kv.v_scale.at[kv.page_ids, kv.page_off].set(
-                vs.astype(kv.v_scale.dtype), mode="drop"))
-    return dataclasses.replace(
-        kv,
-        k=kv.k.at[kv.page_ids, kv.page_off].set(
-            k.astype(kv.k.dtype), mode="drop"),
-        v=kv.v.at[kv.page_ids, kv.page_off].set(
-            v.astype(kv.v.dtype), mode="drop"))
+            k=kv.k.at[kv.page_ids, kv.page_off].set(
+                k.astype(kv.k.dtype), mode="drop"),
+            v=kv.v.at[kv.page_ids, kv.page_off].set(
+                v.astype(kv.v.dtype), mode="drop"))
 
 
 def paged_decode_attention_block(
@@ -284,7 +285,9 @@ def paged_decode_attention_block(
                            rope_theta, qk_norm, norm_eps)
     kv = _paged_write(kv, k, v)
     attend = AB.get(AB.resolve(impl))
-    out = attend(q, kv, n_heads=n_heads, head_dim=head_dim, window=window)
+    with jax.named_scope("attention"):
+        out = attend(q, kv, n_heads=n_heads, head_dim=head_dim,
+                     window=window)
     out = out.reshape(B, C, n_heads * head_dim) @ p["wo"].astype(x.dtype)
     return out, kv
 
@@ -323,27 +326,30 @@ def decode_attention_block(
     q, k, v = _project_qkv(p, x, n_heads, n_kv_heads, head_dim, positions,
                            rope_theta, qk_norm, norm_eps)
     slot = jnp.mod(pos, S_max)
-    if int8_cache:
-        sk, sv = kv_scales
-        kq, ks = quantize_kv_int8(k)
-        vq, vs = quantize_kv_int8(v)
-        cache_k = jax.lax.dynamic_update_slice(cache_k, kq, (0, slot, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(cache_v, vq, (0, slot, 0, 0))
-        sk = jax.lax.dynamic_update_slice(
-            sk, ks.astype(sk.dtype), (0, slot, 0, 0))
-        sv = jax.lax.dynamic_update_slice(
-            sv, vs.astype(sv.dtype), (0, slot, 0, 0))
-        new_scales = (sk, sv)
-        kf32 = cache_k.astype(x.dtype) * sk.astype(x.dtype)
-        vf32 = cache_v.astype(x.dtype) * sv.astype(x.dtype)
-    else:
-        cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k.astype(cache_k.dtype), (0, slot, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v.astype(cache_v.dtype), (0, slot, 0, 0))
-        new_scales = None
-        kf32 = cache_k.astype(x.dtype)
-        vf32 = cache_v.astype(x.dtype)
+    with jax.named_scope("kv"):
+        if int8_cache:
+            sk, sv = kv_scales
+            kq, ks = quantize_kv_int8(k)
+            vq, vs = quantize_kv_int8(v)
+            cache_k = jax.lax.dynamic_update_slice(cache_k, kq,
+                                                   (0, slot, 0, 0))
+            cache_v = jax.lax.dynamic_update_slice(cache_v, vq,
+                                                   (0, slot, 0, 0))
+            sk = jax.lax.dynamic_update_slice(
+                sk, ks.astype(sk.dtype), (0, slot, 0, 0))
+            sv = jax.lax.dynamic_update_slice(
+                sv, vs.astype(sv.dtype), (0, slot, 0, 0))
+            new_scales = (sk, sv)
+            kf32 = cache_k.astype(x.dtype) * sk.astype(x.dtype)
+            vf32 = cache_v.astype(x.dtype) * sv.astype(x.dtype)
+        else:
+            cache_k = jax.lax.dynamic_update_slice(
+                cache_k, k.astype(cache_k.dtype), (0, slot, 0, 0))
+            cache_v = jax.lax.dynamic_update_slice(
+                cache_v, v.astype(cache_v.dtype), (0, slot, 0, 0))
+            new_scales = None
+            kf32 = cache_k.astype(x.dtype)
+            vf32 = cache_v.astype(x.dtype)
     # cell i holds absolute position: i if i <= slot else i + (filled wraps)
     idx = jnp.arange(S_max)
     wraps = (pos // S_max)
@@ -359,21 +365,23 @@ def decode_attention_block(
         AB.position_mask(jnp.asarray(pos, jnp.int32)[None, None],
                          abs_pos[None], window, causal=True)[:, 0],
         NEG_INF)  # [1,S]
-    if gqa_impl == "grouped":
-        KV = n_kv_heads
-        G = n_heads // KV
-        qg = q.reshape(B, 1, KV, G, head_dim)
-        s = jnp.einsum("bqkgh,bskh->bkgqs", qg, kf32) / np.sqrt(head_dim)
-        s = s.astype(jnp.float32) + mask[:, None, None, None, :]
-        probs = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-        out = jnp.einsum("bkgqs,bskh->bqkgh", probs, vf32).reshape(
-            B, 1, n_heads * head_dim)
-    else:
-        kf = _repeat_kv(kf32, n_heads)
-        vf = _repeat_kv(vf32, n_heads)
-        s = jnp.einsum("bqhd,bshd->bhqs", q, kf) / np.sqrt(head_dim)
-        s = s.astype(jnp.float32) + mask[:, None, None, :]
-        probs = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-        out = jnp.einsum("bhqs,bshd->bqhd", probs, vf).reshape(
-            B, 1, n_heads * head_dim)
+    with jax.named_scope("attention"):
+        if gqa_impl == "grouped":
+            KV = n_kv_heads
+            G = n_heads // KV
+            qg = q.reshape(B, 1, KV, G, head_dim)
+            s = jnp.einsum("bqkgh,bskh->bkgqs", qg, kf32) / np.sqrt(
+                head_dim)
+            s = s.astype(jnp.float32) + mask[:, None, None, None, :]
+            probs = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+            out = jnp.einsum("bkgqs,bskh->bqkgh", probs, vf32).reshape(
+                B, 1, n_heads * head_dim)
+        else:
+            kf = _repeat_kv(kf32, n_heads)
+            vf = _repeat_kv(vf32, n_heads)
+            s = jnp.einsum("bqhd,bshd->bhqs", q, kf) / np.sqrt(head_dim)
+            s = s.astype(jnp.float32) + mask[:, None, None, :]
+            probs = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+            out = jnp.einsum("bhqs,bshd->bqhd", probs, vf).reshape(
+                B, 1, n_heads * head_dim)
     return out @ p["wo"].astype(x.dtype), cache_k, cache_v, new_scales

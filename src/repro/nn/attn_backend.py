@@ -245,7 +245,9 @@ def _attend_jnp(q: jax.Array, kv: PagedKV, *, n_heads: int, head_dim: int,
     other backend is gated against this path."""
     B, C = q.shape[0], q.shape[1]
     S = kv.block_tbl.shape[1] * kv.page_size
-    kf, vf = _gathered_views(q, kv)
+    # the gather moves the pool, as the kernel's hand-off does
+    with jax.named_scope("kv"):
+        kf, vf = _gathered_views(q, kv)
     kf = repeat_kv(kf, n_heads)
     vf = repeat_kv(vf, n_heads)
     k_pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))

@@ -13,10 +13,15 @@ Three pieces, all zero-dependency (stdlib + numpy):
   occupancy, prefix hits, COW) and the ``launch.serve`` /
   ``launch.train`` drivers (``--trace`` / ``--metrics-out``).
 
-The instrumented-OFF hot path is unchanged: the device batcher only
-adds its trace leaves (and the jitted step only carries the extra
-scatters) when a tracer is attached, and token streams are bit-exact
-either way (gated by ``benchmarks/check_regression.py``).
+The device batcher's fused step always stamps each request's admission
+and first-token steps (two int32 rows of its output, read with the
+outputs); its ``admitted_at`` / ``first_at`` / ``done_at`` and an
+attached Tracer are fed from those stamps, so the step and the host loop
+are the same code with a tracer or without, and token streams are
+bit-exact either way.  Beside these, the serve path marks itself for the
+JAX profiler: ``jax.named_scope`` names in the fused step (``gate``,
+``kv``, ``attention``, ``mlp`` / ``moe``, ``lm_head``, ``sample``) and
+``serve.*`` host spans in ``DeviceContinuousBatcher.run``.
 """
 from .metrics import Counter, Gauge, Histogram, Metrics
 from .trace import RequestTrace, Tracer, step_time_interp

@@ -62,7 +62,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import heapq
 import time
 import zlib
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -77,7 +76,7 @@ from ..arch.config import ArchConfig
 from ..core.pipeline import MappedModel
 from ..dist import sharding as SH
 from ..nn import attn_backend as AB
-from .faults import PoolExhaust
+from ..obs.trace import step_time_interp
 from .pages import PagePool
 from .pages import page_demand as _page_demand
 
@@ -255,12 +254,12 @@ def _default_seed(request_id) -> int:
 
 
 def _drop_request(b, rid, reason: str, now: Optional[float] = None,
-                  trace: bool = True) -> None:
+                  trace: bool = True, step: Optional[int] = None) -> None:
     """Shared terminal-drop bookkeeping for both batchers: reason +
     wall-clock stamp (``dropped_at`` rides next to ``done_at``), deadline
-    cleanup, tracer/metrics emission.  ``trace=False`` defers emission to
-    the caller — the traced device path emits from the schedule replay so
-    step numbers and interpolated times stay consistent."""
+    cleanup, tracer/metrics emission (``step``: the absolute device step,
+    where known).  ``trace=False`` leaves emission to the caller — the
+    device batcher emits an in-step gate drop at its admission stamp."""
     now = b._clock() if now is None else now
     b.dropped.append(rid)
     b.drop_reasons[rid] = reason
@@ -268,11 +267,12 @@ def _drop_request(b, rid, reason: str, now: Optional[float] = None,
     b.deadline.pop(rid, None)
     if trace and b.tracer is not None:
         if reason == "deadline":
-            b.tracer.deadline_dropped(rid, t=now, shard=b.trace_shard)
+            b.tracer.deadline_dropped(rid, t=now, step=step,
+                                      shard=b.trace_shard)
         elif reason == "quarantined":
-            b.tracer.quarantined(rid, t=now, shard=b.trace_shard)
+            b.tracer.quarantined(rid, t=now, step=step, shard=b.trace_shard)
         else:
-            b.tracer.dropped(rid, reason, t=now)
+            b.tracer.dropped(rid, reason, t=now, step=step)
 
 
 def _defer_full(b, rid, prompt, feat, dabs) -> None:
@@ -360,6 +360,15 @@ class ServeEngine:
         # 'auto' resolves via MappedModel.select_backend (fused Pallas EB
         # kernel on TPU for gate-sized tables, jnp oracle elsewhere)
         self.gate_fn = gate.jax_predict(gate_backend) if gate else None
+        self._admit = None
+        if self.gate_fn is not None:
+            gate_only = self.gate_fn
+
+            def admit_labels(feats):
+                with jax.named_scope("gate"):
+                    return gate_only(feats)
+
+            self._admit = jax.jit(admit_labels)
         # the decode cache is lazy: only the host-driven paths (step /
         # generate / ContinuousBatcher) touch engine.state, and
         # DeviceContinuousBatcher keeps its own donated cache — eager
@@ -481,7 +490,7 @@ class ServeEngine:
         """
         if self.gate_fn is None:
             return np.ones(len(features), bool)
-        labels = np.asarray(self.gate_fn(jnp.asarray(features)))
+        labels = np.asarray(self._admit(jnp.asarray(features)))
         return labels != self.scfg.gate_action_drop
 
     # --------------------------------------------------------------- decode
@@ -982,7 +991,6 @@ class DeviceContinuousBatcher:
         self._drains = 0
         self._retry_q: collections.deque = collections.deque()
         self._exh_holds: List[list] = []
-        self._host_drops: Dict[int, Tuple[int, str, float]] = {}
         self._vocab = engine.cfg.vocab_size
         # mesh defaults to the engine's: a placed engine serves a placed
         # batcher unless the caller explicitly overrides
@@ -1014,6 +1022,9 @@ class DeviceContinuousBatcher:
         self.queue: collections.deque = collections.deque()
         self.done: dict = {}
         self.done_at: dict = {}
+        # host times from the fused step's stamps (see run())
+        self.admitted_at: dict = {}
+        self.first_at: dict = {}
         self.dropped: list = []
         self.drop_reasons: dict = {}
         self.dropped_at: dict = {}
@@ -1033,10 +1044,10 @@ class DeviceContinuousBatcher:
 
     def attach_obs(self, tracer=None, metrics=None) -> None:
         """Attach a ``repro.obs`` Tracer/Metrics pair (None detaches).
-        Tracing never touches the fused step: the traced and untraced
-        paths share the same jitted kernel (same cache entry), and
-        request lifecycles are reconstructed after each drain by
-        replaying the deterministic fill schedule on the host."""
+        The fused step and the host loop are the same either way: the
+        step always stamps each request's admission and first-token
+        steps, and an attached Tracer is fed from those stamps after
+        each call (``run()``)."""
         self.tracer = tracer
         self.metrics = metrics
         if tracer is not None and metrics is not None \
@@ -1130,11 +1141,11 @@ class DeviceContinuousBatcher:
 
     # ------------------------------------------------------------- step fn
     def _make_run_k(self, n_queue: int, n_out: int, n_feat: int) -> Callable:
-        # NOTE: tracing adds NOTHING here.  The traced path runs this
-        # same jitted step (same cache key, byte-identical HLO); request
-        # lifecycles are reconstructed on the host by replaying the
-        # deterministic FIFO fill schedule against the observed
-        # outcomes — see the `traced` block in run().
+        # The step stamps every output row with its local step (``step``
+        # counts the call's steps from 1): ``out_admit`` when the row
+        # takes a slot, ``out_first`` when it yields its first token.
+        # run() reads them with the outputs; a Tracer attached or not,
+        # this is the same program.
         cfg = self.engine.cfg
         gate_fn = self.engine.gate_fn
         scfg = self.engine.scfg
@@ -1151,8 +1162,12 @@ class DeviceContinuousBatcher:
             cand = st["head"] + rank
             take = free & (cand < nq)
             idx = jnp.clip(cand, 0, Nq - 1)
+            t = st["step"] + 1
             st = dict(
                 st,
+                step=t,
+                out_admit=st["out_admit"].at[
+                    jnp.where(take, qreq[idx], R)].set(t, mode="drop"),
                 req=jnp.where(take, qreq[idx], st["req"]),
                 last=jnp.where(take, qtok[idx], st["last"]),
                 feat=jnp.where(take[:, None], qfeat[idx], st["feat"]),
@@ -1177,12 +1192,14 @@ class DeviceContinuousBatcher:
                     # sync_every / wave boundaries can't perturb it
                     logits, dec = M.decode_step(params, st["decode"],
                                                 tok, cfg)
-                    nxt = S.sample_tokens(logits, st["seed"], gen,
-                                          temp, top_k, top_p)
+                    with jax.named_scope("sample"):
+                        nxt = S.sample_tokens(logits, st["seed"], gen,
+                                              temp, top_k, top_p)
                 # slot-level admission: the fused gate's verdict evicts a
                 # just-filled slot before its first token is recorded
                 if gate_fn is not None:
-                    labels = gate_fn(st["feat"])
+                    with jax.named_scope("gate"):
+                        labels = gate_fn(st["feat"])
                     gdrop = active & st["hasf"] & (labels == drop)
                 else:
                     gdrop = jnp.zeros_like(free)
@@ -1193,12 +1210,16 @@ class DeviceContinuousBatcher:
                 out_tok = st["out_tok"].at[
                     widx, jnp.minimum(gen, max_tokens - 1)].set(
                         nxt, mode="drop")
+                out_first = st["out_first"].at[
+                    jnp.where(live & (gen == 0), req, R)].set(
+                        st["step"], mode="drop")
                 gen = gen + live.astype(jnp.int32)
                 fin = live & ((gen >= max_tokens) | (nxt == eos))
                 fidx = jnp.where(fin, req, R)
                 return dict(
                     st,
                     decode=dec,
+                    out_first=out_first,
                     free=free | gdrop | fin,
                     gen=gen,
                     last=jnp.where(live, nxt, st["last"]),
@@ -1264,6 +1285,9 @@ class DeviceContinuousBatcher:
           reference transfers to the prefix cache (the host registers
           them from the ``out_tbl`` ring at drain).  A page frees when
           its count reaches zero.
+
+        It stamps output rows as the dense step does (``out_admit``,
+        ``out_first``).
         """
         cfg = self.engine.cfg
         scfg = self.engine.scfg
@@ -1353,8 +1377,12 @@ class DeviceContinuousBatcher:
             extra = {}
             if share:
                 extra["qidx"] = jnp.where(take, idx, st["qidx"])
+            t = st["step"] + 1
             st = dict(
                 st,
+                step=t,
+                out_admit=st["out_admit"].at[
+                    jnp.where(take, qreq[idx], R)].set(t, mode="drop"),
                 req=jnp.where(take, qreq[idx], st["req"]),
                 plen=jnp.where(take, qlen[idx], st["plen"]),
                 pos=jnp.where(take, qstart[idx], st["pos"]),
@@ -1408,7 +1436,8 @@ class DeviceContinuousBatcher:
                         jnp.where(jj == 0, st["last"][:, None], 0))
                 chunk = jnp.where(jj < c[:, None], chunk, 0)
                 if gate_fn is not None:
-                    labels = gate_fn(st["feat"])
+                    with jax.named_scope("gate"):
+                        labels = gate_fn(st["feat"])
                     gdrop = active & st["hasf"] & (labels == drop)
                 else:
                     gdrop = jnp.zeros_like(free)
@@ -1422,8 +1451,9 @@ class DeviceContinuousBatcher:
                     logits, pages = M.paged_decode_step(
                         params, st["pages"], st["tbl"], pos, chunk, c,
                         cfg, attn_impl=attn_impl)
-                    nxt = S.sample_tokens(logits, st["seed"], gen,
-                                          temp, top_k, top_p)
+                    with jax.named_scope("sample"):
+                        nxt = S.sample_tokens(logits, st["seed"], gen,
+                                              temp, top_k, top_p)
                 if SK == 0:
                     pos = pos + c
                     rec = active & (pos >= plen)  # prompt consumed
@@ -1492,8 +1522,9 @@ class DeviceContinuousBatcher:
                         p_fin = jnp.take_along_axis(
                             probs, fidx_r[:, None, None], axis=1)[:, 0]
                         kpos = gen + jnp.where(decoding, acc, 0)
-                        bonus = S.sample_tokens(l_fin, st["seed"], kpos,
-                                                temp, top_k, top_p)
+                        with jax.named_scope("sample"):
+                            bonus = S.sample_tokens(l_fin, st["seed"], kpos,
+                                                    temp, top_k, top_p)
                         x_rej = jnp.take_along_axis(
                             chunk,
                             jnp.clip(acc + 1, 0, Call - 1)[:, None],
@@ -1540,6 +1571,10 @@ class DeviceContinuousBatcher:
                         decoding & live, c - 1, 0).sum()
                     spec_acc = st["spec_acc"] + jnp.where(
                         decoding & live, acc, 0).sum()
+                # first token: a live row that had generated none
+                out_first = st["out_first"].at[
+                    jnp.where(live & (st["gen"] == 0), req, R)].set(
+                        st["step"], mode="drop")
                 evict = gdrop | fin
                 # drop one reference per table page; a completed reg
                 # slot's full-prompt pages keep theirs (it becomes the
@@ -1571,6 +1606,7 @@ class DeviceContinuousBatcher:
                     tbl=jnp.where(evict[:, None], N, st["tbl"]),
                     pref=pref,
                     out_tok=out_tok,
+                    out_first=out_first,
                     out_len=st["out_len"].at[fidx].set(gen, mode="drop"),
                     out_done=st["out_done"].at[fidx].set(True, mode="drop"),
                     out_drop=out_drop,
@@ -1603,7 +1639,7 @@ class DeviceContinuousBatcher:
         return jax.jit(run_k, donate_argnums=(1,))
 
     # -------------------------------------------------------------- faults
-    def _apply_drain_faults(self, st, req_ids, now, steps_run, traced):
+    def _apply_drain_faults(self, st, req_ids, now, step):
         """Failure handling at ONE host drain boundary: poison
         quarantine, deadline eviction, pool-exhaustion holds.
 
@@ -1611,9 +1647,9 @@ class DeviceContinuousBatcher:
         ``pref``) *between* ``run_k`` calls — the jitted kernel itself
         never sees a fault, so the no-fault path stays byte-identical
         and every run with the same seeded plan replays exactly.
-        Returns the (possibly updated) state and, for traced runs, the
-        ``(step, slots_freed, pages_freed)`` events the schedule replay
-        must fold in so its resource model tracks the real kernel.
+        ``step`` is the absolute device step of the boundary (the
+        tracer's step of an eviction).  Returns the (possibly updated)
+        state.
         """
         inj = self.injector
         shard = self.trace_shard
@@ -1659,7 +1695,6 @@ class DeviceContinuousBatcher:
                 if dabs is not None and now > dabs:
                     evict[b] = "deadline"
         upd: Dict[str, np.ndarray] = {}
-        events: List[Tuple[int, int, int]] = []
         tbl = pref = None
         if evict:
             if self.paged:
@@ -1669,18 +1704,11 @@ class DeviceContinuousBatcher:
                 qi = int(req[b])
                 rid = req_ids[qi]
                 free[b] = True
-                pg = 0
                 if self.paged:
                     valid = tbl[b][tbl[b] < NP]
                     np.subtract.at(pref, valid, 1)
-                    pg = int((pref[valid] == 0).sum())
                     tbl[b] = NP
-                # traced runs emit from the replay (consistent steps +
-                # interpolated times); trace=False defers to it
-                _drop_request(self, rid, reason, now, trace=not traced)
-                if traced:
-                    self._host_drops[qi] = (steps_run, reason, now)
-                    events.append((steps_run + 1, 1, pg))
+                _drop_request(self, rid, reason, now, step=step)
             upd["free"] = free
             if self.paged:
                 upd["tbl"] = tbl
@@ -1710,126 +1738,145 @@ class DeviceContinuousBatcher:
                 upd2 = jax.device_put(
                     upd2, SH.serve_state_shardings(upd2, self.mesh, B))
             st = dict(st, **upd2)
-        return st, events
+        return st
 
     # ----------------------------------------------------------------- run
     def run(self, max_steps: int = 1000) -> dict:
         """Decode until queue + slots drain (or ``max_steps``); returns
         {request_id: tokens}.  Unfinished work survives: in-flight slots
-        and un-admitted queue entries resume on the next ``run()``."""
-        _service_retries(self)
-        pending = list(self.queue)
-        self.queue.clear()
-        carry = [(b, c) for b, c in enumerate(self._carry) if c is not None]
-        if not pending and not carry:
-            if self._retry_q:
-                # nothing to decode but retries are parked: an empty
-                # run() counts as one drain boundary, so backoff elapses
-                # and deferred entries eventually re-enter the queue
-                self._drains += 1
-                _service_retries(self)
-                pending = list(self.queue)
-                self.queue.clear()
-            if not pending:
+        and un-admitted queue entries resume on the next ``run()``.
+
+        Each phase is a host span on the profiler's clock
+        (``jax.profiler.TraceAnnotation``, about a microsecond when no
+        profiler runs): ``serve.admit`` (retries, the gate launch,
+        admission), ``serve.build`` (page plan, in-wave sharing, the
+        numpy arrays), ``serve.upload`` (slot state and queue to the
+        device), then per launch ``serve.launch`` (the fused step's
+        dispatch), ``serve.sync`` (the done-mask read) and
+        ``serve.drain`` (completions and faults; after the last launch
+        the outputs, carry-over and re-queue).
+
+        The fused step stamps each output row with the local step at
+        which it took a slot (``out_admit``) and yielded its first
+        token (``out_first``); they come back in the one read of the
+        outputs.  ``admitted_at`` holds the admission step's host time,
+        interpolated between the launch and the sync
+        (``obs.step_time_interp``); ``first_at`` the time of the sync
+        that handed the first token over, as a streaming client would
+        see it; ``done_at`` the sync that drained the last.  An attached
+        Tracer is fed from the same stamps: the schedule served is the
+        same with or without one.
+        """
+        with jax.profiler.TraceAnnotation("serve.admit"):
+            _service_retries(self)
+            pending = list(self.queue)
+            self.queue.clear()
+            carry = [(b, c) for b, c in enumerate(self._carry)
+                     if c is not None]
+            if not pending and not carry:
+                if self._retry_q:
+                    # nothing to decode but retries are parked: an empty
+                    # run() counts as one drain boundary, so backoff elapses
+                    # and deferred entries eventually re-enter the queue
+                    self._drains += 1
+                    _service_retries(self)
+                    pending = list(self.queue)
+                    self.queue.clear()
+                if not pending:
+                    return self.done
+            eng = self.engine
+            # batched admission: ONE gate launch over the whole waiting queue
+            keep = np.ones(len(pending), bool)
+            gated = [i for i, (_, _, f) in enumerate(pending) if f is not None]
+            if gated and eng.gate_fn is not None and self.pregate:
+                keep[gated] = eng.admit(
+                    np.stack([pending[i][2] for i in gated]))
+            req_ids: List[Any] = [c["rid"] for _, c in carry]
+            kept: List[Tuple[Any, list, Optional[np.ndarray]]] = []
+            now0 = self._clock() if self.deadline else 0.0
+            for k, (rid, prompt, feat) in enumerate(pending):
+                dabs = self.deadline.get(rid)
+                if dabs is not None and now0 > dabs:
+                    # admission-side deadline check: an expired entry never
+                    # enters the wave (or reserves pages)
+                    _drop_request(self, rid, "deadline", now0)
+                    continue
+                if not keep[k]:
+                    _drop_request(self, rid, "gate-reject")
+                    continue
+                req_ids.append(rid)
+                kept.append((rid, prompt, feat))
+            if not req_ids:
                 return self.done
-        eng = self.engine
-        traced = self.tracer is not None
-        if traced and self.spec_k:
-            raise ValueError(
-                "speculative decoding is unsupported on a traced run: "
-                "the schedule replay assumes one emitted token per "
-                "decode step, which an accepted draft chunk violates")
-        # batched admission: ONE gate launch over the whole waiting queue
-        keep = np.ones(len(pending), bool)
-        gated = [i for i, (_, _, f) in enumerate(pending) if f is not None]
-        if gated and eng.gate_fn is not None and self.pregate:
-            keep[gated] = eng.admit(
-                np.stack([pending[i][2] for i in gated]))
-        req_ids: List[Any] = [c["rid"] for _, c in carry]
-        kept: List[Tuple[Any, list, Optional[np.ndarray]]] = []
-        now0 = self._clock() if self.deadline else 0.0
-        for k, (rid, prompt, feat) in enumerate(pending):
-            dabs = self.deadline.get(rid)
-            if dabs is not None and now0 > dabs:
-                # admission-side deadline check: an expired entry never
-                # enters the wave (or reserves pages)
-                _drop_request(self, rid, "deadline", now0)
-                continue
-            if not keep[k]:
-                _drop_request(self, rid, "gate-reject")
-                continue
-            req_ids.append(rid)
-            kept.append((rid, prompt, feat))
-        if not req_ids:
-            return self.done
-        C, n = len(carry), len(kept)
-        n_feat = max(
-            [len(f) for _, _, f in kept if f is not None]
-            + [len(c["feat"]) for _, c in carry if c["feat"] is not None],
-            default=1)
-        # pow2 buckets bound jit retraces across queue sizes
-        Nq = max(8, 1 << (max(1, n) - 1).bit_length())
-        R = max(8, 1 << (C + n - 1).bit_length())
-        if self.paged:
-            longest = max([len(p) for _, p, _ in kept]
-                          + [len(c["prompt"]) for _, c in carry] + [1])
-            p_max = max(4, 1 << (longest - 1).bit_length())
-            qtok = np.zeros((Nq, p_max), np.int32)
-            qlen = np.zeros(Nq, np.int32)
-            scfg = eng.scfg
-            NP, n_ps = scfg.n_pages, scfg.pages_per_slot
-            qsh = np.full((Nq, n_ps), NP, np.int32)
-            qdem = np.zeros(Nq, np.int32)
-            qstart = np.zeros(Nq, np.int32)
-            qcow = np.full(Nq, NP, np.int32)
-            qreg = np.zeros(Nq, bool)
-            qwsrc = np.full(Nq, -1, np.int32)  # in-wave writer queue idx
-            qwneed = np.zeros(Nq, np.int32)  # tokens writer must reach
-            self.pool.begin_wave()
-        else:
-            qtok = np.zeros(Nq, np.int32)
-        qreq = np.zeros(Nq, np.int32)
-        qseed = np.zeros(Nq, np.int32)
-        qfeat = np.zeros((Nq, n_feat), np.int32)
-        qhasf = np.zeros(Nq, bool)
-        # qi -> (prompt, register-on-completion) for drain registration
-        winfo: List[Tuple[list, bool]] = [
-            (c["prompt"], c.get("reg", False)) if self.paged else ([], False)
-            for _, c in carry]
-        wplans: List = []  # kept-index -> PagePlan (stats at drain)
-        for k, (rid, prompt, f) in enumerate(kept):
-            qseed[k] = self.seeds.get(rid, _default_seed(rid))
+        with jax.profiler.TraceAnnotation("serve.build"):
+            C, n = len(carry), len(kept)
+            n_feat = max(
+                [len(f) for _, _, f in kept if f is not None]
+                + [len(c["feat"]) for _, c in carry if c["feat"] is not None],
+                default=1)
+            # pow2 buckets bound jit retraces across queue sizes
+            Nq = max(8, 1 << (max(1, n) - 1).bit_length())
+            R = max(8, 1 << (C + n - 1).bit_length())
             if self.paged:
-                qtok[k, : len(prompt)] = prompt
-                qlen[k] = len(prompt)
-                # prefix-trie plan: shared prefix pages, start offset,
-                # COW source, own-page demand, cache-hold budget verdict
-                plan = self.pool.plan(prompt, self.max_tokens)
-                qsh[k, : len(plan.shared)] = plan.shared
-                qdem[k] = plan.own
-                qstart[k] = plan.start
-                if plan.cow_src is not None:
-                    qcow[k] = plan.cow_src
-                qreg[k] = plan.reg
-                winfo.append((prompt, plan.reg))
-                wplans.append(plan)
+                longest = max([len(p) for _, p, _ in kept]
+                              + [len(c["prompt"]) for _, c in carry] + [1])
+                p_max = max(4, 1 << (longest - 1).bit_length())
+                qtok = np.zeros((Nq, p_max), np.int32)
+                qlen = np.zeros(Nq, np.int32)
+                scfg = eng.scfg
+                NP, n_ps = scfg.n_pages, scfg.pages_per_slot
+                qsh = np.full((Nq, n_ps), NP, np.int32)
+                qdem = np.zeros(Nq, np.int32)
+                qstart = np.zeros(Nq, np.int32)
+                qcow = np.full(Nq, NP, np.int32)
+                qreg = np.zeros(Nq, bool)
+                qwsrc = np.full(Nq, -1, np.int32)  # in-wave writer queue idx
+                qwneed = np.zeros(Nq, np.int32)  # tokens writer must reach
+                self.pool.begin_wave()
             else:
-                winfo.append(([], False))
-                qtok[k] = prompt[0]
-            qreq[k] = C + k  # output row: carryover rows come first
-            if f is not None:
-                qfeat[k, : len(f)] = f[:n_feat]
-                qhasf[k] = True
-        wave_pins: List[int] = []  # host pins on in-wave shared pages
-        wave_deps = False  # any reader waiting on an in-wave writer?
-        if self.paged and eng.scfg.share_prefix:
-            # pressure-release cached prefixes (LRU leaf-first) so the
-            # wave's largest own-demand can eventually be met; pages the
-            # wave itself shares are pinned
-            keep_pin = set(int(p) for p in qsh[qsh < NP])
-            keep_pin |= set(int(p) for p in qcow[qcow < NP])
-            self.pool.ensure_free(int(qdem.max(initial=0)), keep_pin)
-            if not traced:
+                qtok = np.zeros(Nq, np.int32)
+            qreq = np.zeros(Nq, np.int32)
+            qseed = np.zeros(Nq, np.int32)
+            qfeat = np.zeros((Nq, n_feat), np.int32)
+            qhasf = np.zeros(Nq, bool)
+            # qi -> (prompt, register-on-completion) for drain registration
+            winfo: List[Tuple[list, bool]] = [
+                (c["prompt"], c.get("reg", False)) if self.paged
+                else ([], False)
+                for _, c in carry]
+            wplans: List = []  # kept-index -> PagePlan (stats at drain)
+            for k, (rid, prompt, f) in enumerate(kept):
+                qseed[k] = self.seeds.get(rid, _default_seed(rid))
+                if self.paged:
+                    qtok[k, : len(prompt)] = prompt
+                    qlen[k] = len(prompt)
+                    # prefix-trie plan: shared prefix pages, start offset,
+                    # COW source, own-page demand, cache-hold budget verdict
+                    plan = self.pool.plan(prompt, self.max_tokens)
+                    qsh[k, : len(plan.shared)] = plan.shared
+                    qdem[k] = plan.own
+                    qstart[k] = plan.start
+                    if plan.cow_src is not None:
+                        qcow[k] = plan.cow_src
+                    qreg[k] = plan.reg
+                    winfo.append((prompt, plan.reg))
+                    wplans.append(plan)
+                else:
+                    winfo.append(([], False))
+                    qtok[k] = prompt[0]
+                qreq[k] = C + k  # output row: carryover rows come first
+                if f is not None:
+                    qfeat[k, : len(f)] = f[:n_feat]
+                    qhasf[k] = True
+            wave_pins: List[int] = []  # host pins on in-wave shared pages
+            wave_deps = False  # any reader waiting on an in-wave writer?
+            if self.paged and eng.scfg.share_prefix:
+                # pressure-release cached prefixes (LRU leaf-first) so the
+                # wave's largest own-demand can eventually be met; pages the
+                # wave itself shares are pinned
+                keep_pin = set(int(p) for p in qsh[qsh < NP])
+                keep_pin |= set(int(p) for p in qcow[qcow < NP])
+                self.pool.ensure_free(int(qdem.max(initial=0)), keep_pin)
                 # --- in-wave prefix sharing: cold entries (no cache
                 # hit) of THIS wave with identical full-page prefixes
                 # share pages from wave 0 instead of only benefiting
@@ -1837,8 +1884,7 @@ class DeviceContinuousBatcher:
                 # entry owning a prefix node WRITES it during prefill;
                 # later entries READ it (their fused-step admission
                 # waits until the writer's position covers the read
-                # chain).  Disabled under tracing: the schedule replay
-                # does not model admission waits.
+                # chain).
                 page = eng.scfg.page_size
                 cold = [k for k in range(n)
                         if qstart[k] == 0 and qcow[k] == NP
@@ -1904,170 +1950,204 @@ class DeviceContinuousBatcher:
                             wplans[k], shared=chain,
                             start=int(qstart[k]), own=int(qdem[k]))
 
-        B = self._B
-        free = np.ones(B, bool)
-        req = np.full(B, R, np.int32)
-        gen = np.zeros(B, np.int32)
-        last = np.zeros(B, np.int32)
-        feat = np.zeros((B, n_feat), np.int32)
-        hasf = np.zeros(B, bool)
-        seed = np.zeros(B, np.int32)
-        out_tok = np.zeros((R, self.max_tokens), np.int32)
-        if self.paged:
-            scfg = eng.scfg
-            pos = np.zeros(B, np.int32)
-            plen = np.zeros(B, np.int32)
-            pbuf = np.zeros((B, p_max), np.int32)
-            tbl = np.full((B, scfg.pages_per_slot), scfg.n_pages, np.int32)
-            reg = np.zeros(B, bool)
-        for row, (b, c) in enumerate(carry):  # resume in-flight slots
-            free[b] = False
-            req[b] = row
-            gen[b] = c["gen"]
-            last[b] = c["last"]
-            hasf[b] = c["hasf"]
-            seed[b] = c.get("seed", _default_seed(c["rid"]))
-            if c["feat"] is not None:
-                feat[b, : len(c["feat"])] = c["feat"][:n_feat]
-            out_tok[row, : c["gen"]] = c["toks"]
+            B = self._B
+            free = np.ones(B, bool)
+            req = np.full(B, R, np.int32)
+            gen = np.zeros(B, np.int32)
+            last = np.zeros(B, np.int32)
+            feat = np.zeros((B, n_feat), np.int32)
+            hasf = np.zeros(B, bool)
+            seed = np.zeros(B, np.int32)
+            out_tok = np.zeros((R, self.max_tokens), np.int32)
             if self.paged:
-                pos[b] = c["pos"]
-                plen[b] = len(c["prompt"])
-                pbuf[b, : len(c["prompt"])] = c["prompt"]
-                tbl[b] = c["tbl"]
-                reg[b] = c.get("reg", False)
-        st = {
-            "free": jnp.asarray(free),
-            "req": jnp.asarray(req),
-            "gen": jnp.asarray(gen),
-            "last": jnp.asarray(last),
-            "feat": jnp.asarray(feat),
-            "hasf": jnp.asarray(hasf),
-            "seed": jnp.asarray(seed),
-            "head": jnp.int32(0),
-            "out_tok": jnp.asarray(out_tok),
-            "out_len": jnp.zeros(R, jnp.int32),
-            "out_done": jnp.zeros(R, bool),
-            "out_drop": jnp.zeros(R, bool),
-        }
-        pref0 = (self.pool.ref.copy() if self.paged and traced else None)
-        if self.paged:
-            st.update(
-                pages=self._pages,
-                pos=jnp.asarray(pos),
-                plen=jnp.asarray(plen),
-                pbuf=jnp.asarray(pbuf),
-                tbl=jnp.asarray(tbl),
-                reg=jnp.asarray(reg),
-                pref=jnp.asarray(self.pool.ref),
-                out_tbl=jnp.full((R, scfg.pages_per_slot), scfg.n_pages,
-                                 jnp.int32),
-            )
-            if scfg.share_prefix:
-                # carried slots' queue entries are gone: qidx = -1
-                st["qidx"] = jnp.full(B, -1, jnp.int32)
-                st["wdone"] = jnp.zeros(Nq, bool)
-            if self.spec_k:
-                st["spec_prop"] = jnp.int32(0)
-                st["spec_acc"] = jnp.int32(0)
-            args = (jnp.asarray(qtok), jnp.asarray(qlen),
-                    jnp.asarray(qreq), jnp.asarray(qfeat),
-                    jnp.asarray(qhasf), jnp.asarray(qsh),
-                    jnp.asarray(qdem), jnp.asarray(qstart),
-                    jnp.asarray(qcow), jnp.asarray(qreg),
-                    jnp.asarray(qseed), jnp.asarray(qwsrc),
-                    jnp.asarray(qwneed), jnp.int32(n))
-        else:
-            st["decode"] = self._decode
-            args = (jnp.asarray(qtok), jnp.asarray(qreq),
-                    jnp.asarray(qfeat), jnp.asarray(qhasf),
-                    jnp.asarray(qseed), jnp.int32(n))
-        if self.mesh is not None:
-            # place the donated slot pytree (decode cache per cache_pspec
-            # or page pool per paged_cache_pspec, slot arrays over data,
-            # rings replicated for the host drain) and the device FIFO
-            # queue; every subsequent run_k call then computes under
-            # GSPMD on the mesh
-            from jax.sharding import NamedSharding
+                scfg = eng.scfg
+                pos = np.zeros(B, np.int32)
+                plen = np.zeros(B, np.int32)
+                pbuf = np.zeros((B, p_max), np.int32)
+                tbl = np.full((B, scfg.pages_per_slot), scfg.n_pages, np.int32)
+                reg = np.zeros(B, bool)
+            for row, (b, c) in enumerate(carry):  # resume in-flight slots
+                free[b] = False
+                req[b] = row
+                gen[b] = c["gen"]
+                last[b] = c["last"]
+                hasf[b] = c["hasf"]
+                seed[b] = c.get("seed", _default_seed(c["rid"]))
+                if c["feat"] is not None:
+                    feat[b, : len(c["feat"])] = c["feat"][:n_feat]
+                out_tok[row, : c["gen"]] = c["toks"]
+                if self.paged:
+                    pos[b] = c["pos"]
+                    plen[b] = len(c["prompt"])
+                    pbuf[b, : len(c["prompt"])] = c["prompt"]
+                    tbl[b] = c["tbl"]
+                    reg[b] = c.get("reg", False)
+        with jax.profiler.TraceAnnotation("serve.upload"):
+            st = {
+                "free": jnp.asarray(free),
+                "req": jnp.asarray(req),
+                "gen": jnp.asarray(gen),
+                "last": jnp.asarray(last),
+                "feat": jnp.asarray(feat),
+                "hasf": jnp.asarray(hasf),
+                "seed": jnp.asarray(seed),
+                "head": jnp.int32(0),
+                "out_tok": jnp.asarray(out_tok),
+                "out_len": jnp.zeros(R, jnp.int32),
+                "out_done": jnp.zeros(R, bool),
+                "out_drop": jnp.zeros(R, bool),
+                # step stamps (0 = not in this call)
+                "step": jnp.int32(0),
+                "out_admit": jnp.zeros(R, jnp.int32),
+                "out_first": jnp.zeros(R, jnp.int32),
+            }
+            if self.paged:
+                st.update(
+                    pages=self._pages,
+                    pos=jnp.asarray(pos),
+                    plen=jnp.asarray(plen),
+                    pbuf=jnp.asarray(pbuf),
+                    tbl=jnp.asarray(tbl),
+                    reg=jnp.asarray(reg),
+                    pref=jnp.asarray(self.pool.ref),
+                    out_tbl=jnp.full((R, scfg.pages_per_slot), scfg.n_pages,
+                                     jnp.int32),
+                )
+                if scfg.share_prefix:
+                    # carried slots' queue entries are gone: qidx = -1
+                    st["qidx"] = jnp.full(B, -1, jnp.int32)
+                    st["wdone"] = jnp.zeros(Nq, bool)
+                if self.spec_k:
+                    st["spec_prop"] = jnp.int32(0)
+                    st["spec_acc"] = jnp.int32(0)
+                args = (jnp.asarray(qtok), jnp.asarray(qlen),
+                        jnp.asarray(qreq), jnp.asarray(qfeat),
+                        jnp.asarray(qhasf), jnp.asarray(qsh),
+                        jnp.asarray(qdem), jnp.asarray(qstart),
+                        jnp.asarray(qcow), jnp.asarray(qreg),
+                        jnp.asarray(qseed), jnp.asarray(qwsrc),
+                        jnp.asarray(qwneed), jnp.int32(n))
+            else:
+                st["decode"] = self._decode
+                args = (jnp.asarray(qtok), jnp.asarray(qreq),
+                        jnp.asarray(qfeat), jnp.asarray(qhasf),
+                        jnp.asarray(qseed), jnp.int32(n))
+            if self.mesh is not None:
+                # place the donated slot pytree (decode cache per cache_pspec
+                # or page pool per paged_cache_pspec, slot arrays over data,
+                # rings replicated for the host drain) and the device FIFO
+                # queue; every subsequent run_k call then computes under
+                # GSPMD on the mesh
+                from jax.sharding import NamedSharding
 
-            st = jax.device_put(
-                st, SH.serve_state_shardings(st, self.mesh, B))
-            args = tuple(
-                jax.device_put(a, NamedSharding(
-                    self.mesh, SH.queue_pspec(self.mesh, Nq, a.ndim)))
-                for a in args[:-1]) + args[-1:]
-        if self.paged:
-            key: Tuple = (Nq, R, n_feat, p_max)
-            if key not in self._run_k:
-                self._run_k[key] = self._make_run_k_paged(
-                    Nq, R, n_feat, p_max)
-        else:
-            key = (Nq, R, n_feat)
-            if key not in self._run_k:
-                self._run_k[key] = self._make_run_k(Nq, R, n_feat)
-        run_k = self._run_k[key]
+                st = jax.device_put(
+                    st, SH.serve_state_shardings(st, self.mesh, B))
+                args = tuple(
+                    jax.device_put(a, NamedSharding(
+                        self.mesh, SH.queue_pspec(self.mesh, Nq, a.ndim)))
+                    for a in args[:-1]) + args[-1:]
+            if self.paged:
+                key: Tuple = (Nq, R, n_feat, p_max)
+                if key not in self._run_k:
+                    self._run_k[key] = self._make_run_k_paged(
+                        Nq, R, n_feat, p_max)
+            else:
+                key = (Nq, R, n_feat)
+                if key not in self._run_k:
+                    self._run_k[key] = self._make_run_k(Nq, R, n_feat)
+            run_k = self._run_k[key]
 
         inj = self.injector
-        if (traced and inj is not None
-                and inj.pending_kinds(self.trace_shard, PoolExhaust)):
-            raise ValueError(
-                "pool-exhaust injection is unsupported on a traced run: "
-                "the schedule replay models page releases only at slot "
-                "evictions, so phantom holds would make tracer spans lie")
-        self._host_drops = {}
-        fault_events: List[Tuple[int, int, int]] = []
+        base = self._steps_total  # absolute step of this call's step 0
         seen = np.zeros(R, bool)
+        seen_step = np.zeros(R, np.int64)  # boundary that drained a row
         remaining = max_steps
         alive = True
         steps_run = 0
-        # (device step, host time) sync boundaries: in-flight events get
-        # interpolated host timestamps between them (traced runs only;
-        # the kernel call itself is identical either way)
+        # (local step, host time) at the launch and at each sync: the
+        # stamps' steps map to host times between them
         boundaries = [(0, self._clock())]
         while remaining > 0:
             k = min(self.sync_every, remaining)
-            st, alive = run_k(eng.params, st, *args, jnp.int32(k))
-            done_mask = np.asarray(st["out_done"])  # drain every K
+            with jax.profiler.TraceAnnotation("serve.launch"):
+                st, alive = run_k(eng.params, st, *args, jnp.int32(k))
+            with jax.profiler.TraceAnnotation("serve.sync"):
+                # drain every K: the done mask and the alive flag
+                done_mask, alive = jax.device_get((st["out_done"], alive))
             now = self._clock()
-            # nominal cumulative count — only the final trip can exit
-            # early, and the traced tail boundary is clamped to the
-            # replayed schedule's actual last step below
-            steps_run += k
-            if traced:
+            with jax.profiler.TraceAnnotation("serve.drain"):
+                # nominal cumulative count — only the final trip can
+                # exit early; the stamps' step counter clamps it below
+                steps_run += k
                 boundaries.append((steps_run, now))
-            remaining -= k
-            for qi in np.where(done_mask & ~seen)[0]:
-                self.done_at[req_ids[qi]] = now
-                self.deadline.pop(req_ids[qi], None)
-                if traced:
-                    # the same `now` as done_at: drain timestamps and
-                    # tracer spans agree exactly
-                    self.tracer.drained(req_ids[qi], t=now)
-            seen = done_mask
-            self._drains += 1
-            # the fault path is ENTIRELY gated: with no injector, no
-            # deadline and no standing exhaust hold, the drive loop is
-            # the exact pre-fault byte sequence (failure is free when
-            # nothing fails)
-            ft = (bool(self.deadline) or bool(self._exh_holds)
-                  or (inj is not None and inj.pending_for(self.trace_shard)))
-            if ft:
-                st, evs = self._apply_drain_faults(
-                    st, req_ids, now, steps_run, traced)
-                fault_events.extend(evs)
+                remaining -= k
+                fresh = done_mask & ~seen
+                for qi in np.where(fresh)[0]:
+                    self.done_at[req_ids[qi]] = now
+                    self.deadline.pop(req_ids[qi], None)
+                    if self.tracer is not None:
+                        # the same `now` as done_at: drain timestamps
+                        # and tracer spans agree exactly
+                        self.tracer.drained(req_ids[qi], t=now)
+                seen_step[fresh] = steps_run
+                seen = done_mask
+                self._drains += 1
+                # the fault path is ENTIRELY gated: with no injector, no
+                # deadline and no standing exhaust hold, the drive loop
+                # is the exact pre-fault byte sequence (failure is free
+                # when nothing fails)
+                ft = (bool(self.deadline) or bool(self._exh_holds)
+                      or (inj is not None
+                          and inj.pending_for(self.trace_shard)))
+                if ft:
+                    st = self._apply_drain_faults(st, req_ids, now,
+                                                  base + steps_run)
             if not bool(alive):
                 break
+        with jax.profiler.TraceAnnotation("serve.drain"):
+            replan = self._drain(st, bool(alive), carry, kept, req_ids,
+                                 winfo, wplans, wave_pins, base,
+                                 boundaries, seen, seen_step)
+        if (replan and wave_deps and not bool(alive)
+                and remaining > 0 and self.queue):
+            # in-wave readers were left waiting on a writer that died
+            # (gate drop / fault eviction): re-plan them cold — their
+            # next wave sees the writer gone and shares among survivors
+            return self.run(remaining)
+        return self.done
+
+    def _drain(self, st, alive: bool, carry, kept, req_ids, winfo, wplans,
+               wave_pins, base: int, boundaries, seen, seen_step) -> bool:
+        """After a call's last launch: ONE read of the outputs, the
+        stamps and (if the step is still alive) the slots to carry;
+        completions, stamps, the pool mirror, carry-over and re-queue.
+        Returns whether any queue entry was admitted (``head > 0``)."""
+        eng = self.engine
+        C, n = len(carry), len(kept)
+        names = ["out_tok", "out_len", "out_drop", "out_admit",
+                 "out_first", "step", "head"]
+        if self.paged:
+            names += ["pref", "out_tbl"]
+            if self.spec_k:
+                names += ["spec_prop", "spec_acc"]
+        slot_names = ["free", "req", "gen", "last", "feat", "hasf", "seed"]
+        if self.paged:
+            slot_names += ["pos", "plen", "pbuf", "tbl", "reg"]
+        if alive:
+            names += slot_names
+        host = jax.device_get({k2: st[k2] for k2 in names})
+        head = int(host["head"])
         if self.paged:
             self._pages = st["pages"]
-            self.pool.ref[:] = np.asarray(st["pref"])
+            self.pool.ref[:] = host["pref"]
             if wave_pins:
                 # drop the host pins on in-wave shared node pages (live
                 # readers/writers still hold their fill-side refs; a
                 # fully-drained chain frees here)
                 np.subtract.at(self.pool.ref, np.asarray(wave_pins), 1)
             if self.spec_k:
-                self._spec_prop += int(np.asarray(st["spec_prop"]))
-                self._spec_acc += int(np.asarray(st["spec_acc"]))
+                self._spec_prop += int(host["spec_prop"])
+                self._spec_acc += int(host["spec_acc"])
             if self._exh_holds:
                 # phantom holds never outlive the run: the host mirror
                 # must agree with live reservations + cache holds
@@ -2079,215 +2159,14 @@ class DeviceContinuousBatcher:
             # this run (head = queue entries consumed); re-enqueued
             # entries are re-planned — and re-counted — only once they
             # actually land in a slot on a later run
-            for k in range(min(int(np.asarray(st["head"])), n)):
+            for k in range(min(head, n)):
                 self.pool.record_plan(wplans[k], len(kept[k][1]))
         else:
             self._decode = st["decode"]
-        out_tok = np.asarray(st["out_tok"])
-        out_len = np.asarray(st["out_len"])
-        out_drop = np.asarray(st["out_drop"])
-        out_tbl = (np.asarray(st["out_tbl"]) if self.paged else None)
-        if traced:
-            # Request lifecycles are *replayed*, not recorded.  The
-            # fused step's fill is a deterministic function of the FIFO
-            # queue, the slot-free schedule and (paged) the pool's
-            # free-page count, and an admitted slot advances every step
-            # until eviction — so given the observed outcomes (out_len,
-            # out_drop, done mask) the host reconstructs exactly:
-            #   admit:  next FIFO head lands when a slot is free (and,
-            #           paged, the pool covers its own-page demand);
-            #           a slot freed at step s refills at s + 1
-            #   first = admit + ceil((plen - start) / chunk) - 1
-            #           (dense: first = admit — fill and decode share
-            #           the step)
-            #   done  = first + n_tokens - 1 (a gate-dropped slot dies
-            #           on its admit step)
-            # The traced kernel IS the untraced kernel (same jit cache
-            # entry): tracing costs the device nothing.  Steps map to
-            # host times by interpolating between the sync boundaries;
-            # base makes them absolute across runs.
-            NP = eng.scfg.n_pages if self.paged else 0
-            Ck = self.prefill_chunk if self.paged else 1
-            s_admit: List = [None] * (C + n)  # fresh admits only
-            s_first: List = [None] * (C + n)
-            s_done: List = [None] * (C + n)
-            events: List[Tuple[int, int, int]] = []  # step, slots, pages
-            for qi in range(C):
-                # resumed slot, occupied from step 1: admit (and, once
-                # generating, first) were reported by the run that
-                # observed them
-                cst = carry[qi][1]
-                g0 = int(cst["gen"])
-                if self.paged and g0 == 0:  # resumed mid-prefill
-                    rem = len(cst["prompt"]) - int(cst["pos"])
-                    s_first[qi] = max(-(-rem // Ck), 1)
-                if seen[qi]:
-                    s_done[qi] = (s_first[qi] + int(out_len[qi]) - 1
-                                  if s_first[qi] is not None
-                                  else int(out_len[qi]) - g0)
-                elif out_drop[qi]:  # defensive: gate fires on step 1
-                    s_done[qi] = 1
-                if s_done[qi] is not None:
-                    pg = 0
-                    if self.paged:
-                        # pages released at evict = refcount exactly 1
-                        # at run start (shared pages keep the prefix
-                        # cache's standing hold, so they never free
-                        # mid-run); a completed reg slot keeps its
-                        # full-prompt positions for the cache
-                        tbl_c = np.asarray(cst["tbl"])
-                        own = (tbl_c < NP) & (
-                            pref0[np.clip(tbl_c, 0, NP - 1)] == 1)
-                        if cst.get("reg", False) and seen[qi]:
-                            nfp = len(cst["prompt"]) // eng.scfg.page_size
-                            own[:nfp] = False
-                        pg = int(own.sum())
-                    heapq.heappush(events, (s_done[qi] + 1, 1, pg))
-            for ev in fault_events:
-                # host-side fault evictions (deadline / quarantine) free
-                # their slot and pages one step past the drain boundary
-                # they fired at — fold them into the resource model so
-                # the replayed fill keeps matching the kernel's
-                heapq.heappush(events, ev)
-            free_slots = B - C
-            free_pages = int((pref0 == 0).sum()) if self.paged else 0
-            step, qp = 1, 0
-            while qp < n and step <= steps_run:
-                qi = C + qp
-                dem = int(qdem[qp]) if self.paged else 0
-                if free_slots < 1 or (self.paged and dem > free_pages):
-                    # blocked: resources only change at evictions
-                    if not events:
-                        break  # starved — the kernel idles out too
-                    s2, sl, pg = heapq.heappop(events)
-                    if s2 > steps_run:
-                        break
-                    step = max(step, s2)
-                    free_slots += sl
-                    free_pages += pg
-                    continue
-                s_admit[qi] = step
-                free_slots -= 1
-                free_pages -= dem
-                if out_drop[qi]:  # gate verdict evicts on admit step
-                    s_done[qi] = step
-                    heapq.heappush(events, (step + 1, 1, dem))
-                else:
-                    if self.paged:
-                        pre = -(-(int(qlen[qp]) - int(qstart[qp])) // Ck)
-                    else:
-                        pre = 1
-                    s_first[qi] = step + max(pre, 1) - 1
-                    if seen[qi]:
-                        s_done[qi] = s_first[qi] + int(out_len[qi]) - 1
-                        held = 0
-                        if self.paged and qreg[qp]:
-                            nsh = int((qsh[qp] < NP).sum())
-                            page = eng.scfg.page_size
-                            held = min(
-                                max(int(qlen[qp]) // page - nsh, 0), dem)
-                        heapq.heappush(
-                            events, (s_done[qi] + 1, 1, dem - held))
-                    # else: carried out in-flight — releases nothing
-                qp += 1
-            admitted = sum(1 for s in s_admit if s is not None)
-            head_dev = int(np.asarray(st["head"]))
-            if admitted != head_dev:
-                raise RuntimeError(
-                    "obs: schedule replay diverged from the device "
-                    f"fill (replayed {admitted} admits, kernel "
-                    f"consumed {head_dev}) — tracer spans would lie")
-            # actual executed steps: one past the last eviction (the
-            # step that found no work), capped at the nominal count;
-            # any in-flight slot means the loop ran every trip in full
-            dsteps = [s for s in s_done if s is not None]
-            in_flight = any(
-                (qi < C or s_admit[qi] is not None) and s_done[qi] is None
-                for qi in range(C + n))
-            actual = (steps_run if in_flight else
-                      min(steps_run, (max(dsteps) if dsteps else 0) + 1))
-            if boundaries[-1][0] > actual:
-                boundaries[-1] = (actual, boundaries[-1][1])
-            base = self._steps_total
-            self._steps_total += actual
-            gen_end = {}  # row -> generated count, for carried-out rows
-            if alive:
-                tf, trq, tg = jax.device_get(
-                    (st["free"], st["req"], st["gen"]))
-                for b in range(B):
-                    if not tf[b]:
-                        gen_end[int(trq[b])] = int(tg[b])
-            tracer, shard = self.tracer, self.trace_shard
-            rids = list(req_ids)
-            host_drops = dict(self._host_drops)
-
-            def emit():
-                # one vectorised step->time interpolation per event
-                # class (same clamped piecewise-linear map as
-                # obs.step_time_interp, minus 3N python-level calls)
-                b_s = np.array([s for s, _ in boundaries], float)
-                b_t = np.array([t for _, t in boundaries], float)
-
-                def interp_all(steps):
-                    return np.interp(
-                        [0 if s is None else s for s in steps], b_s, b_t)
-
-                t_adm = interp_all(s_admit)
-                t_fst = interp_all(s_first)
-                t_don = interp_all(s_done)
-                for qi in range(C + n):
-                    rid = rids[qi]
-                    if qi >= C:
-                        if s_admit[qi] is None:
-                            continue  # still queued: no events this run
-                        tracer.admitted(rid, t=float(t_adm[qi]),
-                                        step=base + s_admit[qi],
-                                        shard=shard)
-                        if out_drop[qi]:
-                            tracer.dropped(rid, "gate-reject",
-                                           t=float(t_don[qi]),
-                                           step=base + s_done[qi])
-                            continue
-                    hd = host_drops.get(qi)
-                    if hd is not None:
-                        # host fault eviction: terminal at the drain
-                        # boundary that observed it (recorded wall time
-                        # + absolute device step)
-                        step_h, reason, t_h = hd
-                        if (s_first[qi] is not None
-                                and s_first[qi] <= step_h
-                                and gen_end.get(qi, 1) >= 1):
-                            tracer.first_token(rid, t=float(t_fst[qi]),
-                                               step=base + s_first[qi])
-                        if reason == "deadline":
-                            tracer.deadline_dropped(
-                                rid, t=t_h, step=base + step_h,
-                                shard=shard)
-                        else:
-                            tracer.quarantined(
-                                rid, t=t_h, step=base + step_h,
-                                shard=shard)
-                        continue
-                    if seen[qi]:
-                        if s_first[qi] is not None:
-                            tracer.first_token(rid, t=float(t_fst[qi]),
-                                               step=base + s_first[qi])
-                        tracer.finished(rid, n_tokens=int(out_len[qi]),
-                                        t=float(t_don[qi]),
-                                        step=base + s_done[qi])
-                    elif out_drop[qi]:
-                        if s_done[qi] is not None:
-                            tracer.dropped(rid, "gate-reject",
-                                           t=float(t_don[qi]),
-                                           step=base + s_done[qi])
-                    elif s_first[qi] is not None and gen_end.get(qi, 0) >= 1:
-                        # carried out mid-run, first token produced
-                        tracer.first_token(rid, t=float(t_fst[qi]),
-                                           step=base + s_first[qi])
-
-            # the replay above is cheap; the per-request emission is
-            # not, so it runs at export time, not on the serve path
-            self.tracer.defer(emit)
+        out_tok, out_len = host["out_tok"], host["out_len"]
+        out_drop = host["out_drop"]
+        self._stamp(host, carry, req_ids, base, boundaries, seen,
+                    seen_step)
         for qi in range(C + n):
             if seen[qi]:
                 self.done[req_ids[qi]] = [
@@ -2299,55 +2178,100 @@ class DeviceContinuousBatcher:
                     prompt = winfo[qi][0]
                     nfp = len(prompt) // eng.scfg.page_size
                     self.pool.register_completed(
-                        prompt, [int(p) for p in out_tbl[qi][:nfp]])
+                        prompt, [int(p) for p in host["out_tbl"][qi][:nfp]])
             elif out_drop[qi]:
-                # traced runs emit the tracer event from the replay
+                # the tracer's event is emitted at the admission stamp
                 _drop_request(self, req_ids[qi], "gate-reject",
                               trace=False)
         # carry in-flight slots + re-enqueue un-admitted entries so a
         # later run() resumes the exact schedule (host-batcher semantics)
+        B = self._B
         self._carry = [None] * B
         if alive:
-            s_free = np.asarray(st["free"])
-            s_req = np.asarray(st["req"])
-            s_gen = np.asarray(st["gen"])
-            s_last = np.asarray(st["last"])
-            s_feat = np.asarray(st["feat"])
-            s_hasf = np.asarray(st["hasf"])
-            s_seed = np.asarray(st["seed"])
-            if self.paged:
-                s_pos = np.asarray(st["pos"])
-                s_plen = np.asarray(st["plen"])
-                s_pbuf = np.asarray(st["pbuf"])
-                s_tbl = np.asarray(st["tbl"])
-                s_reg = np.asarray(st["reg"])
+            s = {k2: host[k2] for k2 in slot_names}
             for b in range(B):
-                if s_free[b]:
+                if s["free"][b]:
                     continue
-                qi = int(s_req[b])
+                qi = int(s["req"][b])
+                g = int(s["gen"][b])
                 self._carry[b] = dict(
-                    rid=req_ids[qi], gen=int(s_gen[b]), last=int(s_last[b]),
-                    hasf=bool(s_hasf[b]),
-                    feat=s_feat[b].copy() if s_hasf[b] else None,
-                    seed=int(s_seed[b]),
-                    toks=out_tok[qi, : s_gen[b]].copy())
+                    rid=req_ids[qi], gen=g, last=int(s["last"][b]),
+                    hasf=bool(s["hasf"][b]),
+                    feat=s["feat"][b].copy() if s["hasf"][b] else None,
+                    seed=int(s["seed"][b]),
+                    toks=out_tok[qi, :g].copy())
                 if self.paged:
                     self._carry[b].update(
-                        pos=int(s_pos[b]),
+                        pos=int(s["pos"][b]),
                         prompt=[int(t)
-                                for t in s_pbuf[b, : s_plen[b]]],
-                        tbl=s_tbl[b].copy(),
-                        reg=bool(s_reg[b]))
+                                for t in s["pbuf"][b, : s["plen"][b]]],
+                        tbl=s["tbl"][b].copy(),
+                        reg=bool(s["reg"][b]))
         # re-enqueue un-admitted entries regardless of the alive flag:
         # with in-wave sharing a reader blocked on a dead writer idles
         # the kernel out (alive False) while its entry is still pending
-        head = int(np.asarray(st["head"]))
         for rid, prompt, f in reversed(kept[head:]):
             self.queue.appendleft((rid, prompt, f))
-        if (wave_deps and not bool(alive) and head > 0
-                and remaining > 0 and self.queue):
-            # in-wave readers were left waiting on a writer that died
-            # (gate drop / fault eviction): re-plan them cold — their
-            # next wave sees the writer gone and shares among survivors
-            return self.run(remaining)
-        return self.done
+        return head > 0
+
+    def _stamp(self, host, carry, req_ids, base: int, boundaries, seen,
+               seen_step) -> None:
+        """Host times of the step stamps (``admitted_at``, ``first_at``)
+        and, with a Tracer attached, its lifecycle events at the stamps'
+        absolute steps (emission deferred to the tracer's first read).
+
+        The done step is ``out_first + out_len - 1`` (a carried row that
+        was already generating: ``out_len - gen``), never past the sync
+        that drained it: with speculation a step can emit several
+        tokens, so there it is an upper bound."""
+        C = len(carry)
+        rows = len(req_ids)
+        actual = int(host["step"])  # steps the call executed
+        if boundaries[-1][0] > actual:
+            boundaries[-1] = (max(actual, boundaries[-2][0]),
+                              boundaries[-1][1])
+        self._steps_total = base + actual
+        interp = step_time_interp(boundaries)
+        sync_steps = np.array([s for s, _ in boundaries[1:]])
+        adm = host["out_admit"][:rows]
+        fst = host["out_first"][:rows]
+        for qi in np.nonzero(adm)[0]:
+            self.admitted_at[req_ids[qi]] = interp(int(adm[qi]))
+        for qi in np.nonzero(fst)[0]:
+            j = min(int(np.searchsorted(sync_steps, fst[qi])),
+                    len(sync_steps) - 1)
+            self.first_at[req_ids[qi]] = boundaries[j + 1][1]
+        if self.tracer is None:
+            return
+        tracer, shard = self.tracer, self.trace_shard
+        rids = list(req_ids)
+        gen0 = [int(c["gen"]) for _, c in carry]
+        out_len, out_drop = host["out_len"], host["out_drop"]
+        seen, seen_step = seen.copy(), seen_step.copy()
+
+        def emit():
+            for qi in range(rows):
+                rid = rids[qi]
+                a, f = int(adm[qi]), int(fst[qi])
+                if a:
+                    tracer.admitted(rid, t=interp(a), step=base + a,
+                                    shard=shard)
+                if out_drop[qi] and not seen[qi]:
+                    # the in-step gate verdict evicts on the admit step
+                    d = a or 1
+                    tracer.dropped(rid, "gate-reject", t=interp(d),
+                                   step=base + d)
+                    continue
+                if f:
+                    tracer.first_token(rid, t=interp(f), step=base + f)
+                if seen[qi]:
+                    n_tok = int(out_len[qi])
+                    d = (f + n_tok - 1 if f else
+                         n_tok - (gen0[qi] if qi < C else 0))
+                    d = max(min(d, int(seen_step[qi]), actual), f, 1)
+                    tracer.finished(rid, n_tokens=n_tok, t=interp(d),
+                                    step=base + d)
+
+        # the per-request emission runs at export time, not on the
+        # serve path
+        tracer.defer(emit)

@@ -3,13 +3,19 @@
 The integration half pins the ``repro.obs`` contract on real serve
 runs: traced token streams bit-identical to untraced, every admitted
 request reaching exactly one terminal event (including across bounded
-run() resumes), host ``done_at`` and tracer drain stamps agreeing on
-the same clock, and the schedule-replay step numbers staying absolute
-across runs.  The unit half pins the histogram bucket geometry, the
-in-place metrics reset (cached instrument handles must survive), the
-deferred-emission flush, and the Chrome trace-event JSON schema.
+run() resumes, speculation, in-wave prefix sharing, in-step gate drops
+and pool-exhaust faults), host ``done_at`` and tracer drain stamps
+agreeing on the same clock, and the fused step's stamp steps staying
+absolute across runs.  It also pins the marks the profiler sees: the
+named scopes in the compiled paged step and the batcher's ``serve.*``
+host spans in a CPU profiler trace.  The unit half pins the histogram
+bucket geometry, the in-place metrics reset (cached instrument handles
+must survive), the deferred-emission flush, and the Chrome trace-event
+JSON schema.
 """
+import glob
 import json
+import re
 
 import jax
 import numpy as np
@@ -23,6 +29,8 @@ from repro.obs import Histogram, Metrics, Tracer
 from repro.obs.trace import step_time_interp
 from repro.serve.engine import (ContinuousBatcher, DeviceContinuousBatcher,
                                 ServeConfig, ServeEngine)
+from repro.serve.faults import FaultPlan, PoolExhaust
+from repro.serve.spec import train_draft
 
 DS = load_dataset("unsw", n=2000)
 
@@ -297,3 +305,187 @@ def test_paged_traced_parity_and_prefix_metrics(planted):
     json.dumps(ct)
     assert any(e["ph"] == "X" and e["name"] == "decode"
                for e in ct["traceEvents"])
+
+
+# ------------------------------------------------ integration: step stamps
+SCOPES = ("gate", "kv", "attention", "lm_head", "sample")
+SPANS = ("serve.admit", "serve.build", "serve.upload", "serve.launch",
+         "serve.sync", "serve.drain")
+
+
+def _paged_batcher(planted, scfg_kw=None, **kw):
+    cfg, params, gate = planted
+    scfg = ServeConfig(max_batch=4, cache_len=32, page_size=8,
+                       **(scfg_kw or {}))
+    eng = ServeEngine(cfg, params, scfg, gate=gate)
+    return DeviceContinuousBatcher(eng, eos_token=-1, max_tokens=4,
+                                   sync_every=3, prefill_chunk=4, **kw)
+
+
+def _prompts(n, seed=0):
+    rng = np.random.default_rng(seed)
+    return {rid: [int(t) for t in rng.integers(1, 97, rng.integers(2, 12))]
+            for rid in range(n)}
+
+
+def _serve(cb, prompts, features=True, max_steps=300):
+    for rid, p in prompts.items():
+        cb.submit(rid, p, features=DS.X_test[rid] if features else None)
+    while cb.pending_work():
+        cb.run(max_steps=max_steps)
+    return dict(cb.done)
+
+
+def _draft(planted, prompts):
+    base = _paged_batcher(planted)
+    done = _serve(base, prompts, features=False)
+    chains = [list(prompts[r]) + list(t) for r, t in done.items()]
+    return train_draft(chains, vocab_size=planted[0].vocab_size)
+
+
+def _case(planted, case):
+    """(prompts, submit features?, batcher kwargs maker, ServeConfig
+    kwargs) of each schedule the host replay could not follow."""
+    prompts = _prompts(8)
+    if case == "spec_k":
+        draft = _draft(planted, prompts)
+        return prompts, False, lambda: dict(spec_k=3, draft=draft), {}
+    if case == "in_wave_sharing":
+        # identical cold full-page prefixes in one wave: the readers
+        # wait in the step for the writer's prefill
+        return ({i: [5] * 17 + [i] for i in range(4)}, False, dict,
+                dict(pages=24, share_prefix=True))
+    if case == "pool_exhaust":
+        plan = FaultPlan([PoolExhaust(at_drain=1, hold_drains=3)])
+        return (prompts, False,
+                lambda: dict(fault_injector=plan.injector()), {})
+    assert case == "in_step_gate"  # the gate's verdict lands in the step
+    return prompts, True, lambda: dict(pregate=False), {}
+
+
+def _check_lifecycles(cb, tr, rids):
+    assert tr.validate() == []
+    for rid in rids:
+        r = tr.requests[rid]
+        assert r.terminal is not None, rid
+        if r.terminal == "done":
+            assert (r.t_submit <= r.t_admit <= r.t_first <= r.t_done
+                    <= r.t_drain), rid
+            assert r.step_admit <= r.step_first <= r.step_done, rid
+            assert r.n_tokens == len(cb.done[rid])
+            # the batcher's stamps are the tracer's: admission at the
+            # interpolated step, first token at the sync that handed it
+            # over, no later than the drain
+            assert cb.admitted_at[rid] == r.t_admit
+            assert r.t_first <= cb.first_at[rid] <= cb.done_at[rid]
+            assert cb.done_at[rid] == r.t_drain
+        elif r.t_admit is not None:
+            assert r.step_admit <= r.step_done, rid
+
+
+@pytest.mark.parametrize(
+    "case", ["spec_k", "in_wave_sharing", "pool_exhaust", "in_step_gate"])
+def test_stamped_lifecycles_complete_and_monotone(planted, case):
+    prompts, feats, make_kw, scfg_kw = _case(planted, case)
+    ref = _serve(_paged_batcher(planted, scfg_kw, **make_kw()), prompts,
+                 feats)
+    mx = Metrics()
+    tr = Tracer(metrics=mx)
+    cb = _paged_batcher(planted, scfg_kw, tracer=tr, metrics=mx,
+                        **make_kw())
+    got = _serve(cb, prompts, feats, max_steps=5)  # carried across calls
+    assert got == ref
+    _check_lifecycles(cb, tr, prompts)
+    if case == "spec_k":
+        assert cb.spec_stats()["accepted"] > 0
+    elif case == "in_wave_sharing":
+        assert cb.pool.stats["shared_tokens"] > 0
+    elif case == "pool_exhaust":
+        assert cb.injector.fired
+    else:
+        assert any(r.drop_reason == "gate-reject"
+                   and r.step_admit is not None
+                   for r in tr.requests.values())
+
+
+def test_traced_schedule_identical_with_share_prefix(planted):
+    """A Tracer changes nothing the batcher does: with prefix sharing on
+    (cached and in-wave), traced and untraced runs give the same
+    streams, the same sharing and the same number of device steps."""
+    shared = [5] * 17
+
+    def prompts(seed):
+        rng = np.random.default_rng(seed)
+        return {rid: shared[: int(rng.integers(8, 18))]
+                + [int(t) for t in rng.integers(1, 97, 2)]
+                for rid in range(8)}
+
+    def serve(**kw):
+        cb = _paged_batcher(planted, dict(pages=32, share_prefix=True),
+                            **kw)
+        for seed in (0, 1):  # the second wave hits the trie
+            _serve(cb, {10 * seed + r: p for r, p in prompts(seed).items()},
+                   features=False, max_steps=4)
+        return cb
+
+    un = serve()
+    tr = Tracer()
+    cb = serve(tracer=tr)
+    assert cb.done == un.done
+    assert cb.pool.stats == un.pool.stats
+    assert cb.pool.stats["shared_tokens"] > 0
+    assert cb._steps_total == un._steps_total
+    assert tr.validate() == []
+
+
+@pytest.mark.parametrize("arch,ffn", [("qwen2_1_5b", "mlp"),
+                                      ("qwen2_moe_a2_7b", "moe")])
+def test_scopes_in_compiled_paged_step(planted, arch, ffn):
+    """The profiler's name stack of each device op is the op_name of
+    its HLO metadata: the compiled paged step carries every scope."""
+    _, _, gate = planted
+    cfg = get_smoke_config(arch)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    cb = _paged_batcher((cfg, params, gate))
+    lowered = {}
+    make = cb._make_run_k_paged
+
+    def spy(*a):
+        fn = make(*a)
+
+        def call(*args):
+            lowered["step"] = fn.lower(*args)  # before donation
+            return fn(*args)
+        return call
+
+    cb._make_run_k_paged = spy
+    _serve(cb, _prompts(3), features=True)
+    text = lowered["step"].compile().as_text()
+    parts = {p for n in re.findall(r'op_name="([^"]*)"', text)
+             for p in n.split("/")}
+    assert set(SCOPES + (ffn,)) <= parts
+    assert ({"mlp", "moe"} - {ffn}).isdisjoint(parts)
+
+
+def test_serve_spans_in_cpu_profile(planted, tmp_path):
+    """One run() under the profiler leaves the six ``serve.*`` host
+    spans in the trace, nested in the caller's span."""
+    from jax.profiler import ProfileData
+
+    cb = _paged_batcher(planted)
+    _serve(cb, _prompts(4), features=True)  # compile outside the trace
+    for rid, p in _prompts(4, seed=1).items():
+        cb.submit(100 + rid, p, features=DS.X_test[rid])
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("caller.run"):
+        cb.run(max_steps=6)
+    jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    ev = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+          for p in ProfileData.from_file(path).planes
+          if p.name.startswith("/host:") for line in p.lines
+          for e in line.events]
+    outer, = [(a, b) for n, a, b in ev if n == "caller.run"]
+    inner = {n for n, a, b in ev
+             if n.startswith("serve.") and outer[0] <= a and b <= outer[1]}
+    assert inner == set(SPANS)

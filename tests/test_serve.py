@@ -865,16 +865,24 @@ def test_spec_ctor_validation(engine):
 
 
 def test_spec_traced_run_rejected(engine):
-    """Schedule tracing assumes one emitted token per decode step;
-    combining it with speculation must fail loudly at run()."""
+    """A traced speculative run is no longer rejected: the tracer reads
+    the fused step's own stamps, so speculation serves the same streams
+    traced as untraced and every lifecycle is complete and ordered."""
     from repro.obs import Metrics, Tracer
 
     draft, _ = _trained_draft(engine, _prompts(4))
+
+    def run(**kw):
+        cb = DeviceContinuousBatcher(_paged_engine(engine), eos_token=-1,
+                                     max_tokens=4, sync_every=3,
+                                     prefill_chunk=4, spec_k=2,
+                                     draft=draft, **kw)
+        cb.submit(0, [3, 5], features=DS.X_test[0])
+        return cb, cb.run(max_steps=10)
+
     mx = Metrics()
-    cb = DeviceContinuousBatcher(_paged_engine(engine), eos_token=-1,
-                                 max_tokens=4, sync_every=3,
-                                 prefill_chunk=4, spec_k=2, draft=draft,
-                                 tracer=Tracer(metrics=mx), metrics=mx)
-    cb.submit(0, [3, 5], features=DS.X_test[0])
-    with pytest.raises(ValueError, match="spec"):
-        cb.run(max_steps=10)
+    tr = Tracer(metrics=mx)
+    cb, got = run(tracer=tr, metrics=mx)
+    assert got == run()[1]
+    assert tr.validate() == []
+    assert all(r.terminal is not None for r in tr.requests.values())
