@@ -203,14 +203,14 @@ def measure_paged_attention(*, verbose: bool = True) -> Dict[str, Any]:
     for name, quantized in (("fp32", False), ("int8", True)):
         if quantized:
             kv = AB.PagedKV(
-                k=jnp.zeros((N, page, KV, hd), jnp.int8),
-                v=jnp.zeros((N, page, KV, hd), jnp.int8),
-                k_scale=jnp.ones((N, page, KV, 1), jnp.float32),
-                v_scale=jnp.ones((N, page, KV, 1), jnp.float32))
+                k=jnp.zeros((N, page, KV * hd), jnp.int8),
+                v=jnp.zeros((N, page, KV * hd), jnp.int8),
+                k_scale=jnp.ones((N, page, KV), jnp.float32),
+                v_scale=jnp.ones((N, page, KV), jnp.float32))
             pool_bytes = 1
         else:
-            kv = AB.PagedKV(k=jnp.zeros((N, page, KV, hd), jnp.float32),
-                            v=jnp.zeros((N, page, KV, hd), jnp.float32))
+            kv = AB.PagedKV(k=jnp.zeros((N, page, KV * hd), jnp.float32),
+                            v=jnp.zeros((N, page, KV * hd), jnp.float32))
             pool_bytes = 4
         kv = kv.with_view(tbl, pos, None, None)
         fn = jax.jit(functools.partial(AB.get("jnp"), n_heads=H,

@@ -211,7 +211,7 @@ def phase_kernel_vs_oracle(cfg, params, pool, seed: int) -> None:
     """``paged_decode_step`` on the pool the wave left, under the Pallas
     kernel and under the jnp oracle, on identical inputs: one decode
     step (C=1) and one prefill chunk (C=``PREFILL_CHUNK``)."""
-    n_pages, page = pool.k.shape[1], pool.k.shape[2]
+    n_pages, page = pool.n_pages, pool.page_size
     n_ps = n_pages // MAX_BATCH
     rng = np.random.default_rng(seed)
     tbl = jnp.asarray(np.arange(MAX_BATCH * n_ps, dtype=np.int32).reshape(
@@ -239,10 +239,10 @@ def phase_kernel_vs_oracle(cfg, params, pool, seed: int) -> None:
                       f"{tol:.4f}), argmax agreement {agree:.3f} over "
                       f"{MAX_BATCH} slots")
         check(rel <= tol, f"kernel-vs-oracle logits error {rel} > {tol}")
-    # the attention call alone on layer 0's pool, where the logits
-    # error starts
-    kv = AB.PagedKV(k=pool.k[0], v=pool.v[0]).with_view(
-        tbl, pos[:, None], None, None)
+    # the attention call alone on layer 0 of the stacked pool, where the
+    # logits error starts
+    kv = pool.pool().with_view(tbl, pos[:, None], None, None,
+                               jnp.int32(0))
     q = jax.random.normal(jax.random.PRNGKey(seed), (
         MAX_BATCH, 1, cfg.n_heads, cfg.head_dim_), jnp.bfloat16)
     outs = {}

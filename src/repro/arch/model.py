@@ -462,9 +462,11 @@ def init_paged_kv(cfg: ArchConfig, n_pages: int, page_size: int,
                   kv_dtype: str = "bf16") -> PagedKV:
     """Allocate the physical page pool for the paged KV cache.
 
-    Returns a pool-level :class:`~repro.nn.attn_backend.PagedKV` whose
-    ``k``/``v`` pools are ``[n_layers, n_pages, page, KV, hd]`` (view
-    fields ``None``).  Unlike the dense ``[B, cache_len]`` cache,
+    Returns a stacked :class:`~repro.nn.attn_backend.PagedKV` whose
+    ``k``/``v`` pools are ``[n_layers, n_pages, page, KV * hd]`` (view
+    fields ``None``): lane-dense, the layout the paged kernel reads, so
+    every layer writes and reads it in place by layer index.  Unlike
+    the dense ``[B, cache_len]`` cache,
     memory scales with the *pool*, not slots x max length — a block
     table per slot maps logical positions to pages, so short requests
     pin only the pages they reserve and freed pages recycle to the next
@@ -472,18 +474,19 @@ def init_paged_kv(cfg: ArchConfig, n_pages: int, page_size: int,
     the dense cache).
 
     ``kv_dtype='int8'`` quantizes the pool (the paged analogue of the
-    dense int8 cache): int8 value pools plus f32 per-page scale planes
-    ``[n_layers, n_pages, page, KV, 1]`` in ``k_scale``/``v_scale`` —
+    dense int8 cache): int8 value pools plus f32 per-token, per-head
+    scale planes ``[n_layers, n_pages, page, KV]`` in
+    ``k_scale``/``v_scale`` —
     the pool holds ~2x more tokens per byte at the
     ``quantize_kv_int8`` round-trip bound.
     """
     if cfg.block_pattern or cfg.family == "encdec":
         raise ValueError("paged KV cache supports dense attention "
                          f"stacks only (got family={cfg.family!r})")
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads,
-             cfg.head_dim_)
+    shape = (cfg.n_layers, n_pages, page_size,
+             cfg.n_kv_heads * cfg.head_dim_)
     if kv_dtype == "int8":
-        sshape = shape[:-1] + (1,)
+        sshape = shape[:-1] + (cfg.n_kv_heads,)
         return PagedKV(k=jnp.zeros(shape, jnp.int8),
                        v=jnp.zeros(shape, jnp.int8),
                        k_scale=jnp.zeros(sshape, jnp.float32),
@@ -516,9 +519,12 @@ def paged_decode_step(params, kv, block_tbl, pos, tokens, n_new,
     from ``init_paged_kv`` (bf16, or int8 + scale planes — the int8
     path quantizes on write and dequantizes inside the gathered
     attention, mirroring the dense ``decode_step`` int8 cache).  The
-    pool scans as pytree xs: ``lax.scan`` slices each leaf per layer,
-    the body attaches the per-call view, and the updated per-layer
-    pools restack on the way out.
+    stacked pool rides in the layer scan's carry beside the
+    activations, and only the layer index, the layer's params and its
+    window are scanned over: each layer scatters its chunk's K/V into
+    ``pool[layer, page, off]`` and the attention backend reads that
+    layer's pages in place, so no layer's pool is sliced out, restacked
+    or relaid out.
 
     ``attn_impl`` picks the attention backend
     (``attn_backend.resolve``: ``'jnp'`` | ``'pallas'`` | ``'auto'``);
@@ -540,7 +546,7 @@ def paged_decode_step(params, kv, block_tbl, pos, tokens, n_new,
     kv = kv.pool()  # stray view fields would confuse the layer scan
     impl = AB.resolve(attn_impl)
     B, C = tokens.shape
-    N_pages, page = kv.k.shape[1], kv.k.shape[2]
+    N_pages, page = kv.n_pages, kv.page_size
     n_ps = block_tbl.shape[1]
     positions = pos[:, None] + jnp.arange(C)[None]  # [B, C] absolute
     valid = jnp.arange(C)[None] < n_new[:, None]
@@ -551,21 +557,24 @@ def paged_decode_step(params, kv, block_tbl, pos, tokens, n_new,
     x = params["embed"][tokens].astype(COMPUTE_DTYPE)
     windows = jnp.asarray(layer_windows(cfg))
 
-    def body(x, xs):
-        layer_p, kvl, w = xs
+    def body(carry, xs):
+        x, pool = carry
+        layer_p, w, layer = xs
         h = rms_norm(x, layer_p["ln1"], cfg.norm_eps)
-        out, kvl = A.paged_decode_attention_block(
+        out, pool = A.paged_decode_attention_block(
             layer_p["mixer"], h,
-            kvl.with_view(block_tbl, positions, page_ids, page_off),
+            pool.with_view(block_tbl, positions, page_ids, page_off, layer),
             n_heads=cfg.q_heads, n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.head_dim_, rope_theta=cfg.rope_theta, window=w,
             qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps, impl=impl)
         x = x + out
         x, _ = _ffn(layer_p, cfg, x, moe_impl)
-        return x, kvl.pool()
+        return (x, pool.pool()), None
 
-    x, new_kv = jax.lax.scan(
-        body, x, (params["layers"], kv, windows), unroll=unroll)
+    (x, new_kv), _ = jax.lax.scan(
+        body, (x, kv),
+        (params["layers"], windows, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        unroll=unroll)
     if all_positions:
         logits = lm_head(params, x, cfg)  # [B, C, Vp]
         if sample_greedy:

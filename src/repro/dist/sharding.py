@@ -228,9 +228,10 @@ def cache_shardings(state, mesh, batch: int):
 
 
 def paged_cache_pspec(leaf, mesh) -> P:
-    """PartitionSpec for a paged KV page pool ``[stack, n_pages, page,
-    KV, hd]`` (see ``model.init_paged_kv``) — the int8 pool's f32 scale
-    planes ``[stack, n_pages, page, KV, 1]`` follow the same rule.
+    """PartitionSpec for a stacked paged KV page pool ``[stack,
+    n_pages, page, KV * hd]`` (see ``model.init_paged_kv``) — the int8
+    pool's f32 scale planes ``[stack, n_pages, page, KV]`` follow the
+    same rule.
 
     Physical pages shard over ``data`` (the pool is the per-shard slot
     memory, like the dense cache's batch dim), and the *within-page*
@@ -241,10 +242,9 @@ def paged_cache_pspec(leaf, mesh) -> P:
     whatever collective GSPMD derives for the sharded pool.
     """
     shape = tuple(leaf.shape)
-    if len(shape) != 5:
+    if len(shape) != 4:
         return P(*([None] * len(shape)))
-    return _validated(shape,
-                      (None, data_axis(mesh), MODEL_AXIS, None, None),
+    return _validated(shape, (None, data_axis(mesh), MODEL_AXIS, None),
                       mesh)
 
 
@@ -256,8 +256,8 @@ def paged_kv_shardings(kv, mesh):
     ``model.init_paged_kv`` (``k``/``v`` pools, optional int8
     ``k_scale``/``v_scale`` planes; ``None`` view fields contribute no
     leaves) — but legacy ``(k_pages, v_pages[, scales])`` tuples map
-    the same way.  Every 5-D leaf follows ``paged_cache_pspec``; the
-    scale planes' trailing dim of 1 simply never matches ``model``.
+    the same way.  Every 4-D leaf, value pool or scale plane, follows
+    ``paged_cache_pspec``.
     """
     from jax.sharding import NamedSharding
 

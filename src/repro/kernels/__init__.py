@@ -10,8 +10,9 @@ Kernel index:
   ``ops.bnn_forward``/friends, oracle ``ref.py``.
 * ``paged_attention.py`` — serve-path paged decode attention: walks
   the block table page-by-page via scalar-prefetch BlockSpec index
-  maps, fusing gather + int8 dequant + masked softmax attention in one
-  launch (decode ``C=1`` and prefill-chunk ``[B, C]`` variants).  Its
+  maps over one layer of the stacked pool, read in place, fusing
+  gather + int8 dequant + masked softmax attention in one launch
+  (decode ``C=1`` and prefill-chunk ``[B, C]`` variants).  Its
   oracle is the registered ``"jnp"`` backend in
   ``repro.nn.attn_backend`` (matched to a stated tolerance); selected via
   ``ServeConfig(attn_impl=...)`` / ``--attn-impl``.

@@ -8,13 +8,19 @@ traffic by ``H/KV`` for GQA stacks.  For decode (1 query token) that
 gather traffic *is* the roofline — see ``benchmarks/roofline.py
 --paged-attn`` for the computed bytes.
 
-This kernel fuses the whole read side into one launch.  A scalar-
-prefetch grid ``(B, n_ps)`` walks each slot's block table page by
-page: the prefetched (clipped) table drives the K/V ``BlockSpec``
-index maps, so each physical page is DMA'd HBM->VMEM exactly once, at
+This kernel fuses the whole read side into one launch.  It reads the
+stacked pool ``[n_layers, N_pages, page, KV*hd]`` where it lies, in
+the lane-dense layout it is stored in, with no hand-off relayout: the
+layer index is a scalar-prefetch operand, so the layer scan passes the
+whole pool and one traced index.  A scalar-prefetch grid
+``(B, n_ps / per_step)`` walks each slot's block table, ``per_step``
+pages a step (8 where the table's width allows), each page through its
+own ``BlockSpec``, so that many page DMAs are in flight at once: the
+prefetched (clipped) table and the layer drive the K/V index maps, so
+each physical page of that layer is DMA'd HBM->VMEM exactly once, at
 pool dtype, dequantized (int8 pools: per-page f32 scale planes ride
 along and the multiply happens in registers) and staged into a
-per-slot VMEM view ``[S, KV*hd]``.  The last page step of a slot runs
+per-slot VMEM view ``[S, KV*hd]``.  The last step of a slot runs
 masking + softmax + the value matmul for that slot out of VMEM, one
 KV head at a time against its ``H/KV`` query heads (no repeated K/V).
 VMEM holds one slot's view, ``2*S*KV*hd`` elements, whatever the batch.
@@ -57,41 +63,49 @@ __all__ = ["paged_attention", "paged_attention_hbm_bytes"]
 
 
 def _kernel(n_ps: int, page: int, n_kv: int, hd: int, group: int,
-            n_chunk: int, quantized: bool, out_dtype, tbl_ref, pos_ref,
-            win_ref, q_ref, kp_ref, vp_ref, *rest):
-    """One grid step ``(b, s)``: stage slot b's logical page s into the
-    slot's VMEM view; on the slot's last page, attend for slot b.
+            n_chunk: int, quantized: bool, out_dtype, per_step: int,
+            tbl_ref, pos_ref, win_ref, layer_ref, q_ref, *rest):
+    """One grid step ``(b, s)``: stage slot b's logical pages
+    ``s*per_step ..`` ``(s+1)*per_step - 1`` into the slot's VMEM view;
+    on the slot's last step, attend for slot b.
 
-    ``tbl_ref``/``pos_ref``/``win_ref`` are scalar-prefetch operands in
-    SMEM, read one scalar at a time (the flattened clipped block table
-    also drives the K/V BlockSpec index maps, which is what makes the
-    gather a sequence of page DMAs instead of an HBM materialization).
-    ``q_ref`` is slot b's queries grouped by KV head,
-    ``[1, KV, C*group, hd]`` with row ``c*group + g``."""
-    del tbl_ref
-    if quantized:
-        ks_ref, vs_ref, out_ref, kg, vg = rest
-    else:
-        out_ref, kg, vg = rest
+    ``tbl_ref``/``pos_ref``/``win_ref``/``layer_ref`` are scalar-
+    prefetch operands in SMEM, read one scalar at a time (the flattened
+    clipped block table and the layer also drive the K/V BlockSpec
+    index maps, which is what makes the gather a sequence of page DMAs
+    instead of an HBM materialization).  ``q_ref`` is slot b's queries
+    grouped by KV head, ``[1, KV, C*group, hd]`` with row
+    ``c*group + g``.  ``rest`` holds ``per_step`` K page refs, as many
+    V page refs (each one page of the layer, ``[1, page, KV*hd]``),
+    the scale-plane refs of an int8 pool likewise, then the output and
+    the two VMEM views."""
+    del tbl_ref, layer_ref
+    n_in = (4 if quantized else 2) * per_step
+    pages, (out_ref, kg, vg) = rest[:n_in], rest[n_in:]
     b = pl.program_id(0)
     s = pl.program_id(1)
     f32 = jnp.float32
-    rows = pl.ds(pl.multiple_of(s * page, page), page)
-    if quantized:
-        # dequant in-flight: int8 page * scale plane, one rounding to the
-        # compute dtype (the product of two bf16 values is exact in f32,
-        # so this equals the oracle's multiply in the compute dtype)
-        for h in range(n_kv):
-            cols = pl.ds(h * hd, hd)
-            for src, scl, dst in ((kp_ref, ks_ref, kg), (vp_ref, vs_ref, vg)):
-                sc = scl[0, :, pl.ds(h, 1)].astype(out_dtype).astype(f32)
-                dst[rows, cols] = (src[0, :, cols].astype(f32)
-                                   * sc).astype(out_dtype)
-    else:
-        kg[rows, :] = kp_ref[0].astype(out_dtype)
-        vg[rows, :] = vp_ref[0].astype(out_dtype)
+    for j in range(per_step):
+        kp_ref, vp_ref = pages[j], pages[per_step + j]
+        rows = pl.ds(pl.multiple_of((s * per_step + j) * page, page), page)
+        if quantized:
+            ks_ref, vs_ref = pages[2 * per_step + j], pages[3 * per_step + j]
+            # dequant in-flight: int8 page * scale plane, one rounding to
+            # the compute dtype (the product of two bf16 values is exact
+            # in f32, so this equals the oracle's multiply in the compute
+            # dtype)
+            for h in range(n_kv):
+                cols = pl.ds(h * hd, hd)
+                for src, scl, dst in ((kp_ref, ks_ref, kg),
+                                      (vp_ref, vs_ref, vg)):
+                    sc = scl[0, :, pl.ds(h, 1)].astype(out_dtype).astype(f32)
+                    dst[rows, cols] = (src[0, :, cols].astype(f32)
+                                       * sc).astype(out_dtype)
+        else:
+            kg[rows, :] = kp_ref[0].astype(out_dtype)
+            vg[rows, :] = vp_ref[0].astype(out_dtype)
 
-    @pl.when(s == n_ps - 1)
+    @pl.when(s == n_ps // per_step - 1)
     def _attend():
         n_rows, S = n_chunk * group, n_ps * page
         shape = (n_rows, S)
@@ -118,32 +132,38 @@ def _kernel(n_ps: int, page: int, n_kv: int, hd: int, group: int,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     block_tbl: jax.Array, positions: jax.Array, window,
-                    *, k_scale: Optional[jax.Array] = None,
+                    layer, *, k_scale: Optional[jax.Array] = None,
                     v_scale: Optional[jax.Array] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
-    """Attend ``q [B, C, H, hd]`` over a paged pool through its block
-    table.  Matches the registered ``"jnp"`` backend on the same
-    operands to the tolerance stated in the module docstring.
+    """Attend ``q [B, C, H, hd]`` over one layer of a stacked paged pool
+    through its block table.  Matches the registered ``"jnp"`` backend
+    on the same operands to the tolerance stated in the module
+    docstring.
 
     Args:
       q: projected queries, rope applied, ``[B, C, H, hd]`` (``C=1``
         for pure decode, ``C>1`` for a prefill chunk).
-      k_pages/v_pages: physical pool ``[N_pages, page, KV, hd]``
-        (bf16/f32, or int8 with ``k_scale``/``v_scale`` planes
-        ``[N_pages, page, KV, 1]``).
+      k_pages/v_pages: stacked physical pool
+        ``[n_layers, N_pages, page, KV*hd]`` (bf16/f32, or int8 with
+        ``k_scale``/``v_scale`` planes ``[n_layers, N_pages, page,
+        KV]``), read in place.  One layer's pool is the
+        ``n_layers = 1, layer = 0`` case.
       block_tbl: ``[B, n_ps]`` logical->physical page map (entries may
         exceed the pool; they are clipped exactly like the oracle's
         gather — stale reads are masked by the causal term).
       positions: ``[B, C]`` int32 absolute position per chunk slot.
       window: per-layer scalar (0 = full) — may be traced (stacked
         layer scan), hence passed as a scalar-prefetch operand.
+      layer: which layer of the stack to read — traced in the layer
+        scan, hence a scalar-prefetch operand too.
       interpret: force Pallas interpret mode; ``None`` auto-selects it
         off-TPU (CPU CI runs this exact kernel interpreted).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     B, C, H, hd = q.shape
-    N_pages, page, KV, _ = k_pages.shape
+    _, N_pages, page, lanes = k_pages.shape
+    KV = lanes // hd
     n_ps = block_tbl.shape[1]
     group = H // KV
     dt = q.dtype
@@ -152,47 +172,48 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     gtbl = jnp.clip(block_tbl, 0, N_pages - 1).astype(jnp.int32).reshape(-1)
     pos = positions.astype(jnp.int32).reshape(-1)
     win = jnp.asarray(window, jnp.int32).reshape(1)
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
     # query head kv*group + g (repeat_kv's order) -> [B, KV, C*group, hd]
     qg = q.reshape(B, C, KV, group, hd).transpose(0, 2, 1, 3, 4).reshape(
         B, KV, C * group, hd)
 
-    def page_map(b, s, tbl, *_):
-        return (tbl[b * n_ps + s], 0, 0)
+    # each grid step fetches `per_step` of a slot's pages, one BlockSpec
+    # (one double-buffered DMA) each, so that several page DMAs from HBM
+    # are in flight at once: Mosaic buffers a BlockSpec at most twice
+    per_step = max(m for m in (8, 4, 2, 1) if n_ps % m == 0)
+
+    def page_map(j):
+        def index(b, s, tbl, _pos, _win, lyr):
+            return (lyr[0], tbl[b * n_ps + s * per_step + j], 0, 0)
+        return index
 
     def slot_map(b, s, *_):
         return (b, 0, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, KV, C * group, hd), slot_map),   # q
-        pl.BlockSpec((1, page, KV * hd), page_map),       # k_pages
-        pl.BlockSpec((1, page, KV * hd), page_map),       # v_pages
-    ]
-    # the pool's hand-off to the kernel (a relayout on the TPU) is
-    # traced under the ``kv`` scope, with the page write
-    with jax.named_scope("kv"):
-        operands = [qg, k_pages.reshape(N_pages, page, KV * hd),
-                    v_pages.reshape(N_pages, page, KV * hd)]
-        if quantized:
-            in_specs += [pl.BlockSpec((1, page, KV), page_map)] * 2
-            operands += [k_scale.reshape(N_pages, page, KV),
-                         v_scale.reshape(N_pages, page, KV)]
+    in_specs, operands = [pl.BlockSpec((1, KV, C * group, hd), slot_map)], [qg]
+    for pool, width in ((k_pages, KV * hd), (v_pages, KV * hd),
+                        (k_scale, KV), (v_scale, KV)):
+        if pool is not None:
+            in_specs += [pl.BlockSpec((None, 1, page, width), page_map(j))
+                         for j in range(per_step)]
+            operands += [pool] * per_step
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # gtbl, pos, win
-        grid=(B, n_ps),
+        num_scalar_prefetch=4,  # gtbl, pos, win, layer
+        grid=(B, n_ps // per_step),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, KV, C * group, hd), slot_map),
         scratch_shapes=[pltpu.VMEM((n_ps * page, KV * hd), dt),  # slot K
                         pltpu.VMEM((n_ps * page, KV * hd), dt)],  # slot V
     )
     kern = functools.partial(_kernel, n_ps, page, KV, hd, group, C,
-                             quantized, dt)
+                             quantized, dt, per_step)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, C * group, hd), dt),
         interpret=interpret,
-    )(gtbl, pos, win, *operands)
+    )(gtbl, pos, win, lyr, *operands)
     return out.reshape(B, KV, C, group, hd).transpose(0, 2, 1, 3, 4).reshape(
         B, C, H, hd)
 

@@ -190,28 +190,28 @@ def _paged_write(kv: PagedKV, k: jax.Array, v: jax.Array) -> PagedKV:
     Shared verbatim by every attention backend — the write half is not
     part of the backend contract, so the returned pools are bitwise
     identical no matter which implementation attends afterwards.
-    Out-of-range ``page_ids`` drop the write (padded chunk slots).
-    The int8 pool quantizes per token vector and scatters the f32
-    scale planes alongside the values.
+    ``k``/``v`` ``[B, C, KV, hd]`` land as ``KV * hd``-lane rows at
+    ``[layer, page_ids, page_off]`` of a stacked pool (in place: only
+    the ``B * C`` rows move), or ``[page_ids, page_off]`` of a
+    per-layer one.  Out-of-range ``page_ids`` drop the write (padded
+    chunk slots).  The int8 pool quantizes per token vector and
+    scatters the f32 scale planes alongside the values.
     """
+    B, C = k.shape[:2]
+    at = kv.index(kv.page_ids, kv.page_off)
+
+    def put(pool, rows):
+        return pool.at[at].set(rows.reshape(B, C, -1).astype(pool.dtype),
+                               mode="drop")
+
     with jax.named_scope("kv"):
         if kv.quantized:
             kq, ks = quantize_kv_int8(k)
             vq, vs = quantize_kv_int8(v)
             return dataclasses.replace(
-                kv,
-                k=kv.k.at[kv.page_ids, kv.page_off].set(kq, mode="drop"),
-                v=kv.v.at[kv.page_ids, kv.page_off].set(vq, mode="drop"),
-                k_scale=kv.k_scale.at[kv.page_ids, kv.page_off].set(
-                    ks.astype(kv.k_scale.dtype), mode="drop"),
-                v_scale=kv.v_scale.at[kv.page_ids, kv.page_off].set(
-                    vs.astype(kv.v_scale.dtype), mode="drop"))
-        return dataclasses.replace(
-            kv,
-            k=kv.k.at[kv.page_ids, kv.page_off].set(
-                k.astype(kv.k.dtype), mode="drop"),
-            v=kv.v.at[kv.page_ids, kv.page_off].set(
-                v.astype(kv.v.dtype), mode="drop"))
+                kv, k=put(kv.k, kq), v=put(kv.v, vq),
+                k_scale=put(kv.k_scale, ks), v_scale=put(kv.v_scale, vs))
+        return dataclasses.replace(kv, k=put(kv.k, k), v=put(kv.v, v))
 
 
 def paged_decode_attention_block(
@@ -233,8 +233,10 @@ def paged_decode_attention_block(
     The serve-path analogue of ``decode_attention_block`` for the paged
     cache.  ``kv`` is a :class:`~repro.nn.attn_backend.PagedKV` with
     its per-call view attached (``kv.with_view(block_tbl, positions,
-    page_ids, page_off)`` — the scatter coordinates are precomputed
-    once per step by the caller and shared across layers).  The chunk's
+    page_ids, page_off, layer)`` — the scatter coordinates are
+    precomputed once per step by the caller and shared across layers;
+    ``layer`` names the layer of a stacked pool, which is written and
+    read in place, and is left out for a per-layer pool).  The chunk's
     K/V are scattered into their physical pages (out-of-range ids drop
     the write, which is how padded chunk slots are masked), then every
     query attends over the *logical* view ``k_pages[block_tbl]`` —

@@ -91,25 +91,34 @@ class PagedKV:
 
     Replaces the loose ``(k_pages, v_pages[, (k_scales, v_scales)])``
     tuples + four positional table/position arguments that previously
-    threaded through every paged call site.  Two granularities share
+    threaded through every paged call site.  The pools are lane-dense,
+    in the layout the Pallas kernel reads: one token's K (or V) for all
+    KV heads is one row of ``KV * hd`` lanes.  Two granularities share
     the type:
 
-    * **pool-level** (what ``model.init_paged_kv`` returns and the
-      donated serve state carries): ``k``/``v`` are
-      ``[n_layers, N_pages, page, KV, hd]`` physical pools, int8 pools
-      add f32 ``k_scale``/``v_scale`` planes ``[..., KV, 1]``; all view
-      fields are ``None``.
-    * **per-layer + per-call view** (what one attention call sees):
-      pool leaves without the layer axis, plus ``block_tbl [B, n_ps]``
-      (logical page -> physical page), ``pos [B, C]`` (absolute
-      position per chunk slot), and the precomputed scatter coordinates
-      ``page_ids``/``page_off [B, C]`` (out-of-range ids drop the
-      write — how padded chunk slots are masked).
+    * **stacked** (what ``model.init_paged_kv`` returns and the donated
+      serve state carries): ``k``/``v`` are
+      ``[n_layers, N_pages, page, KV * hd]`` physical pools, int8 pools
+      add f32 ``k_scale``/``v_scale`` planes
+      ``[n_layers, N_pages, page, KV]``.  An attention call on it names
+      its layer in ``layer`` (a traced int32 scalar): the page write and
+      the read both index the stack in place, so the pool is never
+      sliced out or restacked.
+    * **per-layer** (``[N_pages, page, KV * hd]`` pools, scale planes
+      ``[N_pages, page, KV]``, ``layer`` None): one layer's pool on its
+      own, as tests build it; the kernel reads it as the one-layer
+      stack.
 
-    ``None`` fields contribute no pytree leaves, so pool-level
-    instances flow through ``jax.tree.map`` (page copy-on-write),
-    ``lax.scan`` (per-layer slicing), buffer donation and
-    ``NamedSharding`` trees exactly like the old tuples did.
+    The per-call view is ``block_tbl [B, n_ps]`` (logical page ->
+    physical page), ``pos [B, C]`` (absolute position per chunk slot),
+    the precomputed scatter coordinates ``page_ids``/``page_off [B, C]``
+    (out-of-range ids drop the write — how padded chunk slots are
+    masked) and ``layer``; a pool as carried has all of them ``None``.
+
+    ``None`` fields contribute no pytree leaves, so bare pools flow
+    through ``jax.tree.map`` (page copy-on-write), a ``lax.scan``
+    carry, buffer donation and ``NamedSharding`` trees exactly like the
+    old tuples did.
     """
 
     k: jax.Array
@@ -120,6 +129,7 @@ class PagedKV:
     pos: Optional[jax.Array] = None
     page_ids: Optional[jax.Array] = None
     page_off: Optional[jax.Array] = None
+    layer: Optional[jax.Array] = None
 
     @property
     def quantized(self) -> bool:
@@ -130,28 +140,38 @@ class PagedKV:
 
     @property
     def n_pages(self) -> int:
-        return self.k.shape[-4]
+        return self.k.shape[-3]
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[-3]
+        return self.k.shape[-2]
 
     @property
     def nbytes(self) -> int:
         """Total bytes across all array leaves (pool accounting)."""
         return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(self))
 
-    def with_view(self, block_tbl, pos, page_ids, page_off) -> "PagedKV":
+    def with_view(self, block_tbl, pos, page_ids, page_off,
+                  layer=None) -> "PagedKV":
         """Attach the per-call view (table + positions + scatter
-        coordinates) to a pool, for one attention call."""
+        coordinates, and the layer of a stacked pool) to a pool, for
+        one attention call."""
         return dataclasses.replace(self, block_tbl=block_tbl, pos=pos,
-                                   page_ids=page_ids, page_off=page_off)
+                                   page_ids=page_ids, page_off=page_off,
+                                   layer=layer)
 
     def pool(self) -> "PagedKV":
         """Strip the per-call view, keeping only the pools — the form
-        carried in serve state and stacked across layers by scan."""
+        carried in serve state and through the layer scan's carry."""
         return dataclasses.replace(self, block_tbl=None, pos=None,
-                                   page_ids=None, page_off=None)
+                                   page_ids=None, page_off=None,
+                                   layer=None)
+
+    def index(self, *idx) -> tuple:
+        """Index of this call's cells in a pool leaf: ``idx`` prefixed
+        with the layer on a stacked pool, ``idx`` alone on a per-layer
+        one."""
+        return idx if self.layer is None else (self.layer, *idx)
 
     def scales(self) -> Optional[Tuple[jax.Array, jax.Array]]:
         """Legacy ``(k_scales, v_scales)`` tuple, or None (fp pool)."""
@@ -163,7 +183,7 @@ class PagedKV:
 jax.tree_util.register_dataclass(
     PagedKV,
     data_fields=["k", "v", "k_scale", "v_scale", "block_tbl", "pos",
-                 "page_ids", "page_off"],
+                 "page_ids", "page_off", "layer"],
     meta_fields=[],
 )
 
@@ -220,22 +240,21 @@ def valid_impls() -> Tuple[str, ...]:
 
 def _gathered_views(q: jax.Array, kv: PagedKV):
     """Logical [B, n_ps*page, KV, hd] K/V views through the block
-    table, dequantized to ``q.dtype`` — the jnp oracle's gather, also
-    the reference the kernel tests diff against."""
+    table, gathered from the call's layer of the pool and dequantized
+    to ``q.dtype`` — the jnp oracle's gather, also the reference the
+    kernel tests diff against."""
     dt = q.dtype
-    B = q.shape[0]
-    N_pages, page = kv.n_pages, kv.page_size
-    n_ps = kv.block_tbl.shape[1]
-    gtbl = jnp.clip(kv.block_tbl, 0, N_pages - 1)
-    if kv.quantized:
-        kf = (kv.k[gtbl].astype(dt) * kv.k_scale[gtbl].astype(dt)).reshape(
-            B, n_ps * page, *kv.k.shape[2:])
-        vf = (kv.v[gtbl].astype(dt) * kv.v_scale[gtbl].astype(dt)).reshape(
-            B, n_ps * page, *kv.v.shape[2:])
-    else:
-        kf = kv.k[gtbl].reshape(B, n_ps * page, *kv.k.shape[2:])
-        vf = kv.v[gtbl].reshape(B, n_ps * page, *kv.v.shape[2:])
-    return kf.astype(dt), vf.astype(dt)
+    B, hd = q.shape[0], q.shape[-1]
+    S = kv.block_tbl.shape[1] * kv.page_size
+    at = kv.index(jnp.clip(kv.block_tbl, 0, kv.n_pages - 1))
+
+    def view(pool, scale):
+        x = pool[at].astype(dt).reshape(B, S, -1, hd)
+        if scale is not None:
+            x = x * scale[at].astype(dt).reshape(B, S, -1, 1)
+        return x
+
+    return view(kv.k, kv.k_scale), view(kv.v, kv.v_scale)
 
 
 def _attend_jnp(q: jax.Array, kv: PagedKV, *, n_heads: int, head_dim: int,
@@ -245,7 +264,7 @@ def _attend_jnp(q: jax.Array, kv: PagedKV, *, n_heads: int, head_dim: int,
     other backend is gated against this path."""
     B, C = q.shape[0], q.shape[1]
     S = kv.block_tbl.shape[1] * kv.page_size
-    # the gather moves the pool, as the kernel's hand-off does
+    # the gather moves the pool's walked pages
     with jax.named_scope("kv"):
         kf, vf = _gathered_views(q, kv)
     kf = repeat_kv(kf, n_heads)
@@ -260,15 +279,23 @@ def _attend_jnp(q: jax.Array, kv: PagedKV, *, n_heads: int, head_dim: int,
 
 def _attend_pallas(q: jax.Array, kv: PagedKV, *, n_heads: int,
                    head_dim: int, window) -> jax.Array:
-    """The Pallas page-walking kernel (``kernels.paged_attention``).
+    """The Pallas page-walking kernel (``kernels.paged_attention``),
+    reading the call's layer of the stacked pool in place; a per-layer
+    pool is the one-layer stack at layer 0.
 
     Imported lazily so this module stays importable without pulling the
     Pallas toolchain in (and so kernels can import the primitives above
     without a cycle).
     """
     from ..kernels.paged_attention import paged_attention
-    return paged_attention(q, kv.k, kv.v, kv.block_tbl, kv.pos, window,
-                           k_scale=kv.k_scale, v_scale=kv.v_scale)
+    pools = (kv.k, kv.v, kv.k_scale, kv.v_scale)
+    layer = kv.layer
+    if layer is None:
+        pools = jax.tree.map(lambda a: a[None], pools)
+        layer = 0
+    k, v, k_scale, v_scale = pools
+    return paged_attention(q, k, v, kv.block_tbl, kv.pos, window, layer,
+                           k_scale=k_scale, v_scale=v_scale)
 
 
 register("jnp", _attend_jnp)

@@ -93,13 +93,13 @@ def test_paged_attention_int8_close_to_fp():
             window=jnp.int32(0), qk_norm=False, norm_eps=1e-6)
 
     out_fp, _ = run(AB.PagedKV(
-        k=jnp.zeros((N, PAGE, H, hd), jnp.float32),
-        v=jnp.zeros((N, PAGE, H, hd), jnp.float32)))
+        k=jnp.zeros((N, PAGE, H * hd), jnp.float32),
+        v=jnp.zeros((N, PAGE, H * hd), jnp.float32)))
     out_i8, kv8 = run(AB.PagedKV(
-        k=jnp.zeros((N, PAGE, H, hd), jnp.int8),
-        v=jnp.zeros((N, PAGE, H, hd), jnp.int8),
-        k_scale=jnp.zeros((N, PAGE, H, 1), jnp.float32),
-        v_scale=jnp.zeros((N, PAGE, H, 1), jnp.float32)))
+        k=jnp.zeros((N, PAGE, H * hd), jnp.int8),
+        v=jnp.zeros((N, PAGE, H * hd), jnp.int8),
+        k_scale=jnp.zeros((N, PAGE, H), jnp.float32),
+        v_scale=jnp.zeros((N, PAGE, H), jnp.float32)))
     assert kv8.k.dtype == jnp.int8 and kv8.quantized
     scale = float(jnp.max(jnp.abs(out_fp)))
     assert float(jnp.max(jnp.abs(out_fp - out_i8))) < 0.05 * scale
@@ -155,10 +155,11 @@ def test_paged_attention_masks_at_page_boundaries(window):
     D = H * hd
     N_pages = B * n_ps
     p = A.init_attention(jax.random.PRNGKey(0), D, H, H, hd)
+    # stale garbage everywhere, lane-dense [N_pages, PAGE, H * hd]
     k_pages = jnp.asarray(rng.normal(0, 1, (N_pages, PAGE, H, hd)),
-                          jnp.float32)  # stale garbage everywhere
+                          jnp.float32).reshape(N_pages, PAGE, H * hd)
     v_pages = jnp.asarray(rng.normal(0, 1, (N_pages, PAGE, H, hd)),
-                          jnp.float32)
+                          jnp.float32).reshape(N_pages, PAGE, H * hd)
     tbl = jnp.asarray(np.arange(N_pages).reshape(B, n_ps)[:, ::-1]
                       .copy())  # non-contiguous logical->physical map
     x_all = jnp.asarray(rng.normal(0, 1, (B, 2 * PAGE, D)), jnp.float32)
@@ -212,7 +213,7 @@ def test_paged_decode_attention_legacy_call_shape_removed():
     positions = jnp.broadcast_to(jnp.arange(3)[None], (B, 3))
     page_ids = jnp.take_along_axis(tbl, positions // PAGE, axis=1)
     page_off = positions % PAGE
-    kp = jnp.zeros((N, PAGE, H, hd), jnp.float32)
+    kp = jnp.zeros((N, PAGE, H * hd), jnp.float32)
     kv0 = AB.PagedKV(k=kp, v=kp)
     kwargs = dict(n_heads=H, n_kv_heads=H, head_dim=hd, rope_theta=0.0,
                   window=jnp.int32(0), qk_norm=False, norm_eps=1e-6)
@@ -255,8 +256,8 @@ def test_dense_and_paged_share_mask_at_page_boundaries(window):
     x_all = jnp.asarray(rng.normal(0, 1, (B, S_max, D)), jnp.float32)
     ck = jnp.zeros((B, S_max, H, hd), jnp.float32)
     cv = jnp.zeros((B, S_max, H, hd), jnp.float32)
-    kv = AB.PagedKV(k=jnp.zeros((N, PAGE, H, hd), jnp.float32),
-                    v=jnp.zeros((N, PAGE, H, hd), jnp.float32))
+    kv = AB.PagedKV(k=jnp.zeros((N, PAGE, H * hd), jnp.float32),
+                    v=jnp.zeros((N, PAGE, H * hd), jnp.float32))
     kwargs = dict(n_heads=H, n_kv_heads=H, head_dim=hd, rope_theta=1e4,
                   window=jnp.int32(window), qk_norm=False, norm_eps=1e-6)
     dense = jax.jit(lambda *a: A.decode_attention_block(*a, **kwargs))

@@ -160,18 +160,21 @@ def test_serve_pspec_rules():
 
 
 def test_paged_cache_pspec_rules():
-    """Paged page pools [stack, n_pages, page, KV, hd]: pages shard over
-    data, the within-page sequence over model where it divides, and
-    non-dividing dims degrade to replication (small-mesh safe)."""
+    """Paged page pools [stack, n_pages, page, KV * hd] and their int8
+    scale planes [stack, n_pages, page, KV]: pages shard over data, the
+    within-page sequence over model where it divides, and non-dividing
+    dims degrade to replication (small-mesh safe)."""
     mesh = _fake_mesh(data=8, model=16)
-    leaf = jax.ShapeDtypeStruct((4, 64, 32, 2, 64), jnp.bfloat16)
+    leaf = jax.ShapeDtypeStruct((4, 64, 32, 128), jnp.bfloat16)
     assert SH.paged_cache_pspec(leaf, mesh) == P(
-        None, "data", "model", None, None)
+        None, "data", "model", None)
+    leaf = jax.ShapeDtypeStruct((4, 64, 32, 2), jnp.float32)
+    assert SH.paged_cache_pspec(leaf, mesh) == P(
+        None, "data", "model", None)
     # page size not dividing model -> replicated page dim; pool not
     # dividing data -> replicated pages
-    leaf = jax.ShapeDtypeStruct((4, 63, 20, 2, 64), jnp.bfloat16)
-    assert SH.paged_cache_pspec(leaf, mesh) == P(
-        None, None, None, None, None)
+    leaf = jax.ShapeDtypeStruct((4, 63, 20, 128), jnp.bfloat16)
+    assert SH.paged_cache_pspec(leaf, mesh) == P(None, None, None, None)
     # non-pool leaves (defensive): replicate
     leaf = jax.ShapeDtypeStruct((64, 32), jnp.bfloat16)
     assert SH.paged_cache_pspec(leaf, mesh) == P(None, None)
@@ -185,8 +188,8 @@ def test_serve_pspec_paged_leaves():
     mesh = _fake_mesh(data=8, model=16)
     B = 16
     st = {
-        "pages": (jax.ShapeDtypeStruct((4, 64, 32, 2, 64), jnp.bfloat16),
-                  jax.ShapeDtypeStruct((4, 64, 32, 2, 64), jnp.bfloat16)),
+        "pages": (jax.ShapeDtypeStruct((4, 64, 32, 128), jnp.bfloat16),
+                  jax.ShapeDtypeStruct((4, 64, 32, 128), jnp.bfloat16)),
         "pos": jax.ShapeDtypeStruct((B,), jnp.int32),
         "plen": jax.ShapeDtypeStruct((B,), jnp.int32),
         "pbuf": jax.ShapeDtypeStruct((B, 32), jnp.int32),
@@ -195,7 +198,7 @@ def test_serve_pspec_paged_leaves():
     }
     specs = jax.tree_util.tree_map_with_path(
         lambda path, leaf: SH.serve_pspec(path, leaf, mesh, B), st)
-    assert specs["pages"][0] == P(None, "data", "model", None, None)
+    assert specs["pages"][0] == P(None, "data", "model", None)
     assert specs["pos"] == P("data")
     assert specs["plen"] == P("data")
     assert specs["pbuf"] == P("data", None)

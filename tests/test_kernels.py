@@ -169,20 +169,23 @@ def _paged_case(seed, B, C, H, KV, hd, page, n_ps, dtype, quantized):
     tbl = jnp.asarray(rng.permutation(N).reshape(B, n_ps).astype(np.int32))
     pos0 = rng.integers(0, n_ps * page - C + 1, B)
     pos = jnp.asarray(pos0[:, None] + np.arange(C)[None], jnp.int32)
+
+    def pool(draw, dt):  # lane-dense per-layer pool [N, page, KV * hd]
+        return jnp.asarray(draw((N, page, KV, hd)).reshape(N, page, KV * hd),
+                           dt)
+
+    def planes():
+        return jnp.asarray(rng.uniform(0.005, 0.02, (N, page, KV)),
+                           jnp.float32)
+
     if quantized:
         kv = AB.PagedKV(
-            k=jnp.asarray(rng.integers(-127, 128, (N, page, KV, hd)),
-                          jnp.int8),
-            v=jnp.asarray(rng.integers(-127, 128, (N, page, KV, hd)),
-                          jnp.int8),
-            k_scale=jnp.asarray(rng.uniform(0.005, 0.02, (N, page, KV, 1)),
-                                jnp.float32),
-            v_scale=jnp.asarray(rng.uniform(0.005, 0.02, (N, page, KV, 1)),
-                                jnp.float32))
+            k=pool(lambda s: rng.integers(-127, 128, s), jnp.int8),
+            v=pool(lambda s: rng.integers(-127, 128, s), jnp.int8),
+            k_scale=planes(), v_scale=planes())
     else:
-        kv = AB.PagedKV(
-            k=jnp.asarray(rng.normal(0, 1, (N, page, KV, hd)), dtype),
-            v=jnp.asarray(rng.normal(0, 1, (N, page, KV, hd)), dtype))
+        kv = AB.PagedKV(k=pool(lambda s: rng.normal(0, 1, s), dtype),
+                        v=pool(lambda s: rng.normal(0, 1, s), dtype))
     page_ids = jnp.take_along_axis(tbl, jnp.clip(pos // page, 0, n_ps - 1),
                                    axis=1)
     return q, kv.with_view(tbl, pos, page_ids, pos % page)
@@ -253,13 +256,46 @@ def test_paged_attention_kernel_int8(C):
     outs = _run_both(q, kv, 4, 8, jnp.int32(0))
     _assert_kernel_close(outs["jnp"], outs["pallas"])
     # dequantizing the pool up front and running fp must agree closely
+    def deq(pool, scale):
+        N, page, _ = pool.shape
+        return (pool.astype(jnp.float32).reshape(N, page, 2, 8)
+                * scale[..., None]).reshape(N, page, 16)
+
     fp_kv = AB.PagedKV(
-        k=kv.k.astype(jnp.float32) * kv.k_scale,
-        v=kv.v.astype(jnp.float32) * kv.v_scale,
+        k=deq(kv.k, kv.k_scale), v=deq(kv.v, kv.v_scale),
         block_tbl=kv.block_tbl, pos=kv.pos,
         page_ids=kv.page_ids, page_off=kv.page_off)
     fp = _run_both(q, fp_kv, 4, 8, jnp.int32(0))
     np.testing.assert_allclose(outs["pallas"], fp["pallas"], atol=1e-6)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_paged_attention_kernel_reads_its_layer(quantized):
+    """A stacked pool of three layers with distinct contents, read at
+    layer 1: the kernel and the oracle agree with each other, and each
+    is bitwise equal to its own call on layer 1's pool alone — an index
+    map or gather that read a neighbouring layer would show here."""
+    import jax
+    import jax.numpy as jnp
+    from repro.nn import attn_backend as AB
+    H, KV, hd = 4, 2, 8
+    # 4 pages a slot: the kernel fetches all 4 in one grid step
+    cases = [_paged_case(70 + i, 2, 3, H, KV, hd, 4, 4, jnp.float32,
+                         quantized=quantized) for i in range(3)]
+    q, one = cases[1]
+    stack = jax.tree.map(lambda *a: jnp.stack(a),
+                         *[kv.pool() for _, kv in cases])
+    view = (one.block_tbl, one.pos, one.page_ids, one.page_off)
+    got = _run_both(q, stack.with_view(*view, jnp.int32(1)), H, hd,
+                    jnp.int32(0))
+    alone = _run_both(q, one, H, hd, jnp.int32(0))
+    _assert_kernel_close(got["jnp"], got["pallas"])
+    for name in ("jnp", "pallas"):
+        np.testing.assert_array_equal(got[name], alone[name])
+    # the neighbours really differ: layer 0 reads another answer
+    other = _run_both(q, stack.with_view(*view, jnp.int32(0)), H, hd,
+                      jnp.int32(0))
+    assert not np.allclose(other["pallas"], got["pallas"])
 
 
 def test_paged_attention_hbm_bytes_accounting():
@@ -316,8 +352,8 @@ def test_paged_block_pallas_matches_jnp_end_to_end():
     x = jnp.asarray(rng.normal(0, 1, (B, 3, D)), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(3)[None], (B, 3)).astype(jnp.int32)
     page_ids = jnp.take_along_axis(tbl, pos // page, axis=1)
-    kv = AB.PagedKV(k=jnp.zeros((N, page, 2, hd), jnp.float32),
-                    v=jnp.zeros((N, page, 2, hd), jnp.float32))
+    kv = AB.PagedKV(k=jnp.zeros((N, page, 2 * hd), jnp.float32),
+                    v=jnp.zeros((N, page, 2 * hd), jnp.float32))
 
     def run(impl):
         fn = jax.jit(functools.partial(

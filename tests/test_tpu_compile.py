@@ -12,8 +12,11 @@ import this file.  Code that asks ``jax.default_backend()`` still sees the
 CPU here, so the tests that need the compiled kernel steer it with
 ``monkeypatch``.
 """
+import dataclasses
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,19 +89,21 @@ def _shapes(tree, sharding):
 @pytest.mark.parametrize("pool_dtype", ["bf16", "int8"])
 @pytest.mark.parametrize("C", [1, 8])
 def test_paged_attention_compiles_at_qwen2_widths(one_chip, C, pool_dtype):
+    """The kernel on one traced layer of a stacked lane-dense pool."""
     cfg = get_config("qwen2-1.5b")
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
-    N = B * PAGES_PER_SLOT
+    L, N = 2, B * PAGES_PER_SLOT
     pdt = jnp.int8 if pool_dtype == "int8" else jnp.bfloat16
     args = [_spec((B, C, H, hd), jnp.bfloat16, one_chip),
-            _spec((N, PAGE, KV, hd), pdt, one_chip),
-            _spec((N, PAGE, KV, hd), pdt, one_chip),
+            _spec((L, N, PAGE, KV * hd), pdt, one_chip),
+            _spec((L, N, PAGE, KV * hd), pdt, one_chip),
             _spec((B, PAGES_PER_SLOT), jnp.int32, one_chip),
             _spec((B, C), jnp.int32, one_chip),
+            _spec((), jnp.int32, one_chip),
             _spec((), jnp.int32, one_chip)]
     kw = {}
     if pool_dtype == "int8":
-        kw = {k: _spec((N, PAGE, KV, 1), jnp.float32, one_chip)
+        kw = {k: _spec((L, N, PAGE, KV), jnp.float32, one_chip)
               for k in ("k_scale", "v_scale")}
     fn = jax.jit(functools.partial(paged_attention, interpret=False))
     hlo = fn.lower(*args, **kw).compile().as_text()
@@ -151,3 +156,58 @@ def test_full_width_paged_decode_step_compiles(one_chip, on_tpu):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, used
+
+
+# ops that would move a pool, or one layer of it, instead of indexing it
+_MOVES = re.compile(r"= \(?\w+\[([\d,]+)\]\S* (copy|copy-start|dynamic-slice|"
+                    r"dynamic-update-slice|reshape|transpose)\(")
+
+
+def _pool_moves(hlo: str, n_pages: int, layer_cells: int) -> list:
+    """Ops of the optimized HLO whose result holds the pages of the pool
+    (a ``n_pages, PAGE`` run in its dims) and is as large as one layer's
+    pool."""
+    moves = []
+    for m in _MOVES.finditer(hlo):
+        dims = tuple(int(d) for d in m.group(1).split(","))
+        paged = any(dims[i:i + 2] == (n_pages, PAGE)
+                    for i in range(len(dims) - 1))
+        if paged and math.prod(dims) >= layer_cells:
+            moves.append(f"{m.group(2)} {list(dims)}")
+    return moves
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_step_keeps_the_pool_in_place(one_chip, on_tpu, kv_dtype):
+    """The paged step inside a donated ``while_loop`` carry, as the
+    batcher's fused ``run_k`` runs it, at qwen2-1.5b's widths with depth
+    cut to 2: the stacked pool is written by in-place scatters and read
+    by the kernel's page DMAs, with no copy, slice, restack or relayout
+    of the pool or of one layer's pool anywhere in the program."""
+    cfg = dataclasses.replace(get_config("qwen2-1.5b"), n_layers=2)
+    N = B * PAGES_PER_SLOT
+    params = _shapes(jax.eval_shape(
+        functools.partial(M.init_params, cfg), jax.random.PRNGKey(0)),
+        one_chip)
+    pool = _shapes(jax.eval_shape(functools.partial(
+        M.init_paged_kv, cfg, N, PAGE, kv_dtype=kv_dtype)), one_chip)
+    i32 = functools.partial(_spec, dtype=jnp.int32, sharding=one_chip)
+
+    def run_k(params, pool, tbl, pos, toks, n_new, k):
+        def body(c):
+            i, pool, toks = c
+            nxt, pool = M.paged_decode_step(
+                params, pool, tbl, pos + i, toks, n_new, cfg,
+                sample_greedy=True, attn_impl="auto")
+            return i + 1, pool, nxt[:, None]
+
+        _, pool, toks = jax.lax.while_loop(
+            lambda c: c[0] < k, body, (jnp.int32(0), pool, toks))
+        return pool, toks
+
+    hlo = jax.jit(run_k, donate_argnums=(1,)).lower(
+        params, pool, i32((B, PAGES_PER_SLOT)), i32((B,)), i32((B, 1)),
+        i32((B,)), i32(())).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    layer_cells = N * PAGE * cfg.n_kv_heads * cfg.head_dim_
+    assert _pool_moves(hlo, N, layer_cells) == []
