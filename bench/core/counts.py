@@ -7,11 +7,15 @@ happens to move, so any implementation reads against the same work.
 A request of prompt ``L`` and ``T`` output tokens passes through the fused
 step as ``ceil(L / chunk)`` prefill steps (the last one also yields the
 first token) and then ``T - 1`` decode steps.  Query position ``q``
-attends ``q + 1`` keys (causal, itself included).
+attends ``q + 1`` keys (causal, itself included).  What a token costs in
+the layers is the family's to count (``bench/core/models.py``): its
+``linear_flops_per_token``, ``attn_flops`` and ``attn_bytes``.
 """
 from __future__ import annotations
 
 from typing import Dict, List, Tuple
+
+from . import models
 
 BF16 = 2  # bytes of the compute and KV-cache type
 
@@ -35,32 +39,6 @@ def attn_pairs(q0: int, n: int) -> int:
     return n * q0 + n * (n + 1) // 2
 
 
-def linear_flops_per_token(d: Dict[str, int]) -> int:
-    """Matmul FLOPs of one token through every layer, attention scores
-    excluded, LM head excluded (active experts only)."""
-    D, H, KV, hd = d["D"], d["H"], d["KV"], d["hd"]
-    proj = 2 * D * (H + 2 * KV) * hd + 2 * H * hd * D
-    if "E" in d:
-        ffn = 2 * D * d["E"] + d["k"] * 6 * D * d["F"] + 6 * D * d["Fs"]
-    else:
-        ffn = 6 * D * d["F"]
-    return d["L"] * (proj + ffn)
-
-
-def attn_flops(d: Dict[str, int], pairs: int) -> int:
-    """QK^T and PV over ``pairs`` query-key pairs, every layer."""
-    return d["L"] * 4 * d["H"] * d["hd"] * pairs
-
-
-def attn_bytes(d: Dict[str, int], ctx: int, n: int) -> int:
-    """HBM bytes one slot's attention needs at one step, every layer:
-    its ``ctx`` cached keys and values read once, its ``n`` queries read
-    and outputs written."""
-    kv = 2 * ctx * d["KV"] * d["hd"] * BF16
-    qo = 2 * n * d["H"] * d["hd"] * BF16
-    return d["L"] * (kv + qo)
-
-
 def head_flops(d: Dict[str, int]) -> int:
     return 2 * d["D"] * d["V"]
 
@@ -70,23 +48,24 @@ class StepWork:
 
     def __init__(self, d: Dict[str, int]):
         self.d = d
+        self.family = models.load(d)
         self.model_flops: Dict[int, float] = {}
         self.attn_flops: Dict[int, float] = {}
         self.attn_bytes: Dict[int, float] = {}
 
     def add_request(self, admit_step: int, prompt_len: int, out_tokens: int,
                     chunk: int) -> None:
-        d = self.d
-        lin = linear_flops_per_token(d)
+        d, fam = self.d, self.family
+        lin = fam.linear_flops_per_token(d)
         for j, (q0, n, logits) in enumerate(
                 life_steps(prompt_len, out_tokens, chunk)):
             s = admit_step + j
             pairs = attn_pairs(q0, n)
-            af = attn_flops(d, pairs)
+            af = fam.attn_flops(d, pairs)
             mf = n * lin + af + (head_flops(d) if logits else 0)
             self.model_flops[s] = self.model_flops.get(s, 0.0) + mf
             self.attn_flops[s] = self.attn_flops.get(s, 0.0) + af
-            self.attn_bytes[s] = self.attn_bytes.get(s, 0.0) + attn_bytes(
+            self.attn_bytes[s] = self.attn_bytes.get(s, 0.0) + fam.attn_bytes(
                 d, q0 + n, n)
 
     def totals(self, lo: int, hi: int) -> Dict[str, float]:

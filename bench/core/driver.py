@@ -31,6 +31,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import numpy as np
 
+from . import models
 from . import traffic as T
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -187,34 +188,21 @@ class System:
 def program_arch(prog, cfg: dict):
     """The program's config for a config file.  The program's own preset
     gives the architecture; the depth, and the published values of the
-    keys the program states as settings (rotary base, norm epsilon, q/k/v
-    biases), come from the file, so that the program computes the
-    published function wherever it can."""
+    keys the program states as settings, come from the file (the family's
+    ``program_settings``), so that the program computes the published
+    function wherever it can."""
     get = prog["get_smoke_config" if cfg.get("program_smoke")
                else "get_config"]
-    arch = dataclasses.replace(
-        get(cfg["program_arch"]), n_layers=cfg["num_hidden_layers"],
-        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
-        qkv_bias=cfg["attention_bias"])
+    arch = dataclasses.replace(get(cfg["program_arch"]),
+                               **models.load(cfg).program_settings(cfg))
     check_arch(arch, cfg)
     return arch
 
 
 def check_arch(arch, cfg: dict) -> None:
     """The program's config must run the widths and depth the config
-    file states."""
-    want = dict(d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-                n_kv_heads=cfg["num_key_value_heads"],
-                head_dim_=cfg.get("head_dim", cfg["hidden_size"]
-                                  // cfg["num_attention_heads"]),
-                vocab_size=cfg["vocab_size"], n_layers=cfg["num_hidden_layers"])
-    if cfg.get("num_experts"):
-        want.update(d_ff=cfg["moe_intermediate_size"],
-                    n_experts=cfg["num_experts"],
-                    n_experts_active=cfg["num_experts_per_tok"],
-                    shared_d_ff=cfg["shared_expert_intermediate_size"])
-    else:
-        want.update(d_ff=cfg["intermediate_size"])
+    file states (the family's ``program_widths``)."""
+    want = models.load(cfg).program_widths(cfg)
     got = {k: getattr(arch, k) for k in want}
     bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
     if bad:

@@ -5,22 +5,11 @@ kernel, no cache, no batching, nothing imported from the program.  It reads
 the weight tree the benchmark made (``bench/core/weights.py``) and the
 configuration file: its published values, except where a ``departures``
 entry names a key the program cannot compute as published, which is read
-as run (``weights.as_run``):
-
-* RMSNorm ``x / sqrt(mean(x^2) + eps) * g`` with the gain stored as
-  ``g - 1`` in the tree;
-* q/k/v projections (with bias where ``attention_bias``), rotary
-  embedding on the two halves of each head (``rope_theta``), grouped
-  query heads (head ``h`` reads key/value head ``h // (H / KV)``), causal
-  softmax attention scaled by ``1/sqrt(head_dim)``;
-* SwiGLU MLP ``down(silu(gate x) * up x)``; or the sparse MoE: softmax
-  router over the ``num_experts`` real experts, the ``num_experts_per_tok``
-  largest kept (renormalised to sum one where ``norm_topk_prob``), each a
-  SwiGLU of width ``moe_intermediate_size``, plus one shared SwiGLU of
-  width ``shared_expert_intermediate_size`` added without a gate where
-  ``shared_expert_gate`` is false;
-* final RMSNorm and the head over the ``vocab_size`` real columns: the
-  embedding's transpose where ``tie_word_embeddings``, else its own.
+as run (``weights.as_run``).  The forward is the configuration's family
+module's (``bench/core/models.py``); the pieces families share are here:
+RMSNorm ``x / sqrt(mean(x^2) + eps) * g`` with the gain stored as ``g - 1``
+in the tree, rotary embedding on the two halves of each head, the SwiGLU
+``down(silu(gate x) * up x)``, and every matmul in float32 or fp8.
 
 ``precision="fp8"`` is the control: the same forward with every matmul
 operand rounded to float8 e4m3 (a scale per row of the activation and per
@@ -36,11 +25,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .core import models
 from .core.weights import as_run
 
 F8_MAX = 448.0  # largest finite float8 e4m3 value
-Q_BLOCK = 1024  # query rows per attention block (bounds the score tile)
-MOE_ROWS = 512  # token rows per expert block
 
 
 def _q8(x, axis):
@@ -71,99 +59,15 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
 
 
-def _attention(p, h, cfg, fp8):
-    S = h.shape[0]
-    H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = cfg.get("head_dim", cfg["hidden_size"] // H)
-    q = _mm(h, p["wq"], fp8)
-    k = _mm(h, p["wk"], fp8)
-    v = _mm(h, p["wv"], fp8)
-    if cfg["attention_bias"]:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    pos = jnp.arange(S)
-    q = _rope(q.reshape(S, H, hd), pos, cfg["rope_theta"])
-    k = _rope(k.reshape(S, KV, hd), pos, cfg["rope_theta"])
-    v = v.reshape(S, KV, hd)
-    grp = H // KV
-    k = jnp.repeat(k, grp, axis=1)  # head h reads kv head h // grp
-    v = jnp.repeat(v, grp, axis=1)
-    if fp8:
-        q, k, v = _q8(q, -1), _q8(k, -1), _q8(v, 0)
-    outs = []
-    for q0 in range(0, S, Q_BLOCK):
-        qb = q[q0:q0 + Q_BLOCK]
-        s = jnp.einsum("qhd,khd->hqk", qb, k) / np.sqrt(hd)
-        qi = jnp.arange(q0, q0 + qb.shape[0])
-        s = jnp.where(qi[None, :, None] >= pos[None, None, :], s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        if fp8:
-            w = _q8(w, -1)
-        outs.append(jnp.einsum("hqk,khd->qhd", w, v))
-    o = jnp.concatenate(outs, 0).reshape(S, H * hd)
-    return _mm(o, p["wo"], fp8)
-
-
 def _swiglu(h, wg, wu, wd, fp8):
     return _mm(jax.nn.silu(_mm(h, wg, fp8)) * _mm(h, wu, fp8), wd, fp8)
-
-
-def _moe(p, h, cfg, fp8):
-    if cfg.get("shared_expert_gate"):
-        raise ValueError("a gated shared expert is not computed here")
-    E, k = cfg["num_experts"], cfg["num_experts_per_tok"]
-    logits = _mm(h, p["router"][:, :E], fp8)
-    gates = jax.nn.softmax(logits, axis=-1)
-    top, idx = jax.lax.top_k(gates, k)
-    if cfg["norm_topk_prob"]:
-        top = top / jnp.sum(top, -1, keepdims=True)
-    comb = jnp.sum(jax.nn.one_hot(idx, E) * top[..., None], axis=1)  # [S,E]
-    wg, wu, wd = (p["w_gate"][:E], p["w_up"][:E], p["w_down"][:E])
-    hq = h
-    if fp8:
-        hq = _q8(h, -1)
-        wg, wu, wd = _q8(wg, 1), _q8(wu, 1), _q8(wd, 1)
-    outs = []
-    for r0 in range(0, h.shape[0], MOE_ROWS):  # bounds the [rows, E, F] tile
-        hb, cb = hq[r0:r0 + MOE_ROWS], comb[r0:r0 + MOE_ROWS]
-        a = jax.nn.silu(jnp.einsum("sd,edf->sef", hb, wg)) * jnp.einsum(
-            "sd,edf->sef", hb, wu)
-        if fp8:
-            a = _q8(a, -1)
-        outs.append(jnp.einsum("sef,efd->sd", a * cb[..., None], wd))
-    out = jnp.concatenate(outs, 0)
-    sp = p["shared"]
-    return out + _swiglu(h, sp["w_gate"], sp["w_up"], sp["w_down"], fp8)
-
-
-def _forward(cfg, fp8, params, tokens, where):
-    """Logits ``[len(where), vocab_size]`` at the positions ``where`` of
-    the token sequence ``tokens`` (causal: padding after the last real
-    token changes nothing before it)."""
-    eps = cfg["rms_norm_eps"]
-    x = params["embed"][tokens]
-
-    def layer(x, lp):
-        x = x + _attention(lp["mixer"], _rms(x, lp["ln1"], eps), cfg, fp8)
-        h = _rms(x, lp["ln2"], eps)
-        if cfg.get("num_experts"):
-            x = x + _moe(lp["moe"], h, cfg, fp8)
-        else:
-            m = lp["mlp"]
-            x = x + _swiglu(h, m["w_gate"], m["w_up"], m["w_down"], fp8)
-        return x, None
-
-    x, _ = jax.lax.scan(layer, x, params["layers"])
-    h = _rms(x[where], params["ln_f"], eps)
-    V = cfg["vocab_size"]
-    head = (params["embed"][:V].T if cfg["tie_word_embeddings"]
-            else params["head"][:, :V])
-    return _mm(h, head, fp8)
 
 
 @functools.lru_cache(maxsize=None)
 def _compiled(cfg_json: str, precision: str):
     cfg = as_run(json.loads(cfg_json))
-    fwd = functools.partial(_forward, cfg, precision == "fp8")
+    fwd = functools.partial(models.load(cfg).forward, cfg,
+                            precision == "fp8")
 
     def run(params, tokens, where):
         with jax.default_matmul_precision("highest"):
