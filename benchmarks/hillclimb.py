@@ -1,8 +1,8 @@
 """§Perf hillclimb driver: three chosen cells, hypothesis->change->measure.
 
 Cells (from the baseline table):
-  1. moonshot_v1_16b_a3b x train_4k   — worst useful-flops ratio (0.07):
-     dense MoE dispatch computes all 64 experts/token.
+  1. moonlight_16b_a3b x train_4k     — dense MoE dispatch computes all
+     64 experts/token.
   2. internvl2_2b x decode_32k        — most collective-bound: KV-repeat
      forces an involuntary SPMD resharding of the cache.
   3. qwen3_32b x decode_32k           — most representative of the paper's
@@ -17,8 +17,8 @@ from benchmarks.roofline import measure_cell
 
 RUNS = [
     # (cell, label, overrides)
-    (("moonshot_v1_16b_a3b", "train_4k"), "baseline(dense-moe)", {}),
-    (("moonshot_v1_16b_a3b", "train_4k"), "sparse-dispatch",
+    (("moonlight_16b_a3b", "train_4k"), "baseline(dense-moe)", {}),
+    (("moonlight_16b_a3b", "train_4k"), "sparse-dispatch",
      {"moe_impl": "sparse"}),
     (("internvl2_2b", "decode_32k"), "baseline(repeat-gqa)", {}),
     (("internvl2_2b", "decode_32k"), "grouped-gqa",

@@ -34,6 +34,29 @@ class ArchConfig:
     n_experts_active: int = 0
     n_shared_experts: int = 0
     shared_d_ff: int = 0
+    # routing: "softmax" over the experts, or "sigmoid" scores with a
+    # learned correction bias that moves the top-k choice only
+    # (DeepSeek-V3 ``noaux_tc``); the chosen weights are renormalised to
+    # sum one where ``norm_topk``, then scaled by ``routed_scale``
+    router: str = "softmax"
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+    # expert share: the experts lie over ``expert_ranks`` ranks and this
+    # layer holds rank ``expert_rank``'s slice; it routes over all of
+    # them and computes its own
+    expert_ranks: int = 1
+    expert_rank: int = 0
+    # leading dense layers (SwiGLU of ``dense_d_ff``) before the MoE
+    # layers (DeepSeek ``first_k_dense_replace``); counted in n_layers
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
+    # multi-head latent attention (DeepSeek-V2/V3): K and V come from one
+    # ``kv_lora_rank`` latent per token plus a shared rotary key of
+    # ``qk_rope_head_dim``; 0 = grouped-query attention
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # enc-dec
     n_encoder_layers: int = 0
     # modality frontend stub
@@ -69,6 +92,29 @@ class ArchConfig:
         return _round_up(self.n_experts, 16) if self.n_experts else 0
 
     @property
+    def n_experts_local(self) -> int:
+        """Experts this rank holds: its slice of the padded stack."""
+        return self.n_experts_padded // self.expert_ranks
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def kv_lanes(self) -> int:
+        """Lanes of one token's row in a layer of the paged pool: a K
+        (or V) row of every KV head, or under MLA the latent and the
+        rotary key, padded to a multiple of 128 lanes.  The TPU lays out
+        an array whose minor dim is not a multiple of 128 with another
+        dim minor-most; the kernel's page DMAs need the row-major
+        layout, and the step would relayout the whole pool on entry and
+        exit.  In the row-major layout the tiles pad the row to the same
+        width anyway."""
+        if self.mla:
+            return _round_up(self.kv_lora_rank + self.qk_rope_head_dim, 128)
+        return self.n_kv_heads * self.head_dim_
+
+    @property
     def is_subquadratic(self) -> bool:
         """Archs that run long_500k: recurrent state + at most local attn."""
         if not self.block_pattern:
@@ -76,11 +122,22 @@ class ArchConfig:
         return "attn_global" not in self.block_pattern and (
             self.family in ("ssm", "hybrid"))
 
+    def _attn_params(self) -> int:
+        D, hd = self.d_model, self.head_dim_
+        if self.mla:
+            r, dr = self.kv_lora_rank, self.qk_rope_head_dim
+            dq = self.qk_nope_head_dim + dr
+            return (D * self.n_heads * dq + D * (r + dr) + r
+                    + r * self.n_heads * (self.qk_nope_head_dim
+                                          + self.v_head_dim)
+                    + self.n_heads * self.v_head_dim * D)
+        return D * hd * (self.n_heads + 2 * self.n_kv_heads) + \
+            self.n_heads * hd * D
+
     def param_count(self) -> int:
         """Approximate parameter count (dense algebra, for roofline N)."""
-        D, hd = self.d_model, self.head_dim_
-        attn = D * hd * (self.n_heads + 2 * self.n_kv_heads) + \
-            self.n_heads * hd * D
+        D = self.d_model
+        attn = self._attn_params()
         mlp = 3 * D * self.d_ff
         if self.n_experts:
             moe = self.n_experts * 3 * D * self.d_ff + D * self.n_experts
@@ -97,7 +154,8 @@ class ArchConfig:
             n_att = self.n_layers - n_rec
             total = n_att * per_layer + n_rec * (rec + mlp if self.d_ff else rec)
         else:
-            total = self.n_layers * per_layer
+            total = self.n_layers * per_layer + self.n_dense_layers * (
+                3 * D * self.dense_d_ff - mlp)
         if self.n_encoder_layers:
             total += self.n_encoder_layers * (attn + mlp) + \
                 self.n_layers * attn  # cross attention
@@ -109,14 +167,14 @@ class ArchConfig:
         if not self.n_experts:
             return self.param_count()
         D = self.d_model
-        hd = self.head_dim_
-        attn = D * hd * (self.n_heads + 2 * self.n_kv_heads) + \
-            self.n_heads * hd * D
+        attn = self._attn_params()
         act_mlp = self.n_experts_active * 3 * D * self.d_ff + \
             D * self.n_experts
         if self.n_shared_experts:
             act_mlp += 3 * D * self.shared_d_ff
-        return self.n_layers * (attn + act_mlp) + 2 * self.vocab_padded * D
+        return (self.n_layers * (attn + act_mlp)
+                + self.n_dense_layers * (3 * D * self.dense_d_ff - act_mlp)
+                + 2 * self.vocab_padded * D)
 
 
 @dataclasses.dataclass(frozen=True)

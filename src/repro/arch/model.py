@@ -4,7 +4,9 @@ Layers live as *stacked* param pytrees scanned with ``lax.scan`` — one
 compiled layer body regardless of depth (compile-time and remat-friendly;
 the production choice).  Heterogeneous stacks (hybrid/ssm) scan over
 *macro blocks* (the smallest repeating pattern), with any remainder layers
-applied unscanned.
+applied unscanned.  Leading dense layers before an MoE stack (DeepSeek's
+``first_k_dense_replace``) are a stack of their own, ``dense_layers``,
+scanned before ``layers``; they are the first layers of the KV pool.
 
 Param dtype is f32 master; compute casts to bf16 at the embedding.
 """
@@ -48,8 +50,17 @@ def macro_pattern(cfg: ArchConfig) -> Tuple[Tuple[str, ...], int, int]:
     return pat, cfg.n_layers // len(pat), cfg.n_layers % len(pat)
 
 
+def mla_dims(cfg: ArchConfig) -> A.MLA:
+    """The latent-attention widths of an MLA config."""
+    return A.MLA(heads=cfg.n_heads, rank=cfg.kv_lora_rank,
+                 nope=cfg.qk_nope_head_dim, rope=cfg.qk_rope_head_dim,
+                 v=cfg.v_head_dim, theta=cfg.rope_theta, eps=cfg.norm_eps)
+
+
 # ------------------------------------------------------------------ init
 def _init_mixer(key, cfg: ArchConfig, kind: str) -> Params:
+    if kind == "attn" and cfg.mla:
+        return A.init_mla(key, cfg.d_model, mla_dims(cfg))
     if kind in ("attn", "attn_local"):
         p = A.init_attention(key, cfg.d_model, cfg.q_heads,
                              cfg.n_kv_heads, cfg.head_dim_,
@@ -68,30 +79,38 @@ def _init_mixer(key, cfg: ArchConfig, kind: str) -> Params:
     raise ValueError(kind)
 
 
-def _init_layer(key, cfg: ArchConfig, kind: str) -> Params:
+def _init_layer(key, cfg: ArchConfig, kind: str,
+                dense: bool = False) -> Params:
+    """One layer; ``dense`` makes a leading dense layer of an MoE stack
+    (an MLP of ``dense_d_ff``)."""
     k = split_keys(key, 3)
     p: Params = {
         "mixer": _init_mixer(k[0], cfg, kind),
         "ln1": jnp.zeros((cfg.d_model,), jnp.float32),
     }
-    if cfg.d_ff > 0:
+    if dense:
+        p["ln2"] = jnp.zeros((cfg.d_model,), jnp.float32)
+        p["mlp"] = init_mlp(k[1], cfg.d_model, cfg.dense_d_ff)
+    elif cfg.d_ff > 0:
         p["ln2"] = jnp.zeros((cfg.d_model,), jnp.float32)
         if cfg.n_experts:
             p["moe"] = init_moe(k[1], cfg.d_model, cfg.d_ff,
                                 cfg.n_experts_padded, cfg.n_shared_experts,
-                                cfg.shared_d_ff)
+                                cfg.shared_d_ff, cfg.n_experts_local,
+                                router_bias=cfg.router == "sigmoid")
         else:
             p["mlp"] = init_mlp(k[1], cfg.d_model, cfg.d_ff)
     return p
 
 
-def _stack_layers(key, cfg: ArchConfig, kind: str, n: int) -> Params:
+def _stack_layers(key, cfg: ArchConfig, kind: str, n: int,
+                  dense: bool = False) -> Params:
     """``n`` layers' params stacked on a leading axis.  One layer is
     traced and vmapped over the split keys — the same values as
     initializing each layer and stacking, in one layer's program (a
     jitted full-depth init compiles in seconds, not minutes)."""
     keys = jax.random.split(key, n)
-    return jax.vmap(lambda k: _init_layer(k, cfg, kind))(keys)
+    return jax.vmap(lambda k: _init_layer(k, cfg, kind, dense))(keys)
 
 
 def init_params(cfg: ArchConfig, key) -> Params:
@@ -114,7 +133,12 @@ def init_params(cfg: ArchConfig, key) -> Params:
             for i in range(n_tail)
         ]
     else:
-        params["layers"] = _stack_layers(k[2], cfg, "attn", cfg.n_layers)
+        nd = cfg.n_dense_layers
+        if nd:
+            params["dense_layers"] = _stack_layers(k[7], cfg, "attn", nd,
+                                                   dense=True)
+        params["layers"] = _stack_layers(k[2], cfg, "attn",
+                                         cfg.n_layers - nd)
     if cfg.n_encoder_layers:
         params["enc_layers"] = _stack_layers(k[4], cfg, "attn",
                                              cfg.n_encoder_layers)
@@ -128,16 +152,22 @@ def init_params(cfg: ArchConfig, key) -> Params:
 
 # --------------------------------------------------------------- forward
 def _ffn(p: Params, cfg: ArchConfig, x, moe_impl: str):
-    if cfg.d_ff == 0:
+    """The layer's feed-forward: its ``moe`` or ``mlp`` (a leading dense
+    layer of an MoE stack has an ``mlp``)."""
+    moe = "moe" in p
+    if not moe and "mlp" not in p:
         return x, 0.0
     # named scope: the profiler's name stack of every op below (the
     # benchmark's mlp_share / moe_share read it)
-    with jax.named_scope("moe" if cfg.n_experts else "mlp"):
+    with jax.named_scope("moe" if moe else "mlp"):
         h = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if cfg.n_experts:
+        if moe:
             fn = moe_block_sparse if moe_impl == "sparse" else moe_block
             out, aux = fn(p["moe"], h, n_experts=cfg.n_experts,
-                          top_k=cfg.n_experts_active, act=cfg.act)
+                          top_k=cfg.n_experts_active, act=cfg.act,
+                          router=cfg.router, norm_topk=cfg.norm_topk,
+                          routed_scale=cfg.routed_scale,
+                          first=cfg.expert_rank * cfg.n_experts_local)
             return x + out, aux
         return x + mlp_block(p["mlp"], h, cfg.act), 0.0
 
@@ -145,7 +175,10 @@ def _ffn(p: Params, cfg: ArchConfig, x, moe_impl: str):
 def _mixer_fwd(p: Params, cfg: ArchConfig, kind: str, x, window,
                positions, q_block: int, mlstm_chunk: int = 0):
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    if kind in ("attn", "attn_local"):
+    if kind == "attn" and cfg.mla:
+        out = A.mla_block(p["mixer"], h, mla_dims(cfg), positions=positions,
+                          window=window, q_block=q_block)
+    elif kind in ("attn", "attn_local"):
         out = A.attention_block(
             p["mixer"], h, n_heads=cfg.q_heads, n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.head_dim_, rope_theta=cfg.rope_theta, window=window,
@@ -321,8 +354,13 @@ def forward(params, batch, cfg: ArchConfig, *, moe_impl: str = "dense",
                                   remat_policy=remat_policy)
         else:
             windows = layer_windows(cfg)
-            x, aux = _dense_stack(params["layers"], cfg, x, windows, pos,
-                                  moe_impl, q_block, unroll=unroll,
+            nd = cfg.n_dense_layers
+            if nd:
+                x, _ = _dense_stack(params["dense_layers"], cfg, x,
+                                    windows[:nd], pos, moe_impl, q_block,
+                                    unroll=unroll, remat_policy=remat_policy)
+            x, aux = _dense_stack(params["layers"], cfg, x, windows[nd:],
+                                  pos, moe_impl, q_block, unroll=unroll,
                                   mlstm_chunk=mlstm_chunk,
                                   remat_policy=remat_policy)
     logits = lm_head(params, x, cfg)
@@ -479,12 +517,21 @@ def init_paged_kv(cfg: ArchConfig, n_pages: int, page_size: int,
     ``k_scale``/``v_scale`` —
     the pool holds ~2x more tokens per byte at the
     ``quantize_kv_int8`` round-trip bound.
+
+    An MLA config gets one latent pool ``[n_layers, n_pages, page,
+    kv_lanes]`` (``v`` None): a token's row is its normed latent and its
+    rotated shared key (``kv_lora_rank + qk_rope_head_dim`` lanes, then
+    zeros to a multiple of 128), the K and V of every head at once.
     """
     if cfg.block_pattern or cfg.family == "encdec":
         raise ValueError("paged KV cache supports dense attention "
                          f"stacks only (got family={cfg.family!r})")
-    shape = (cfg.n_layers, n_pages, page_size,
-             cfg.n_kv_heads * cfg.head_dim_)
+    shape = (cfg.n_layers, n_pages, page_size, cfg.kv_lanes)
+    if cfg.mla:
+        if kv_dtype != "bf16":
+            raise ValueError(f"the latent (MLA) pool is bf16 only; "
+                             f"kv_dtype={kv_dtype!r} is not supported")
+        return PagedKV(k=jnp.zeros(shape, COMPUTE_DTYPE))
     if kv_dtype == "int8":
         sshape = shape[:-1] + (cfg.n_kv_heads,)
         return PagedKV(k=jnp.zeros(shape, jnp.int8),
@@ -561,19 +608,34 @@ def paged_decode_step(params, kv, block_tbl, pos, tokens, n_new,
         x, pool = carry
         layer_p, w, layer = xs
         h = rms_norm(x, layer_p["ln1"], cfg.norm_eps)
-        out, pool = A.paged_decode_attention_block(
-            layer_p["mixer"], h,
-            pool.with_view(block_tbl, positions, page_ids, page_off, layer),
-            n_heads=cfg.q_heads, n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.head_dim_, rope_theta=cfg.rope_theta, window=w,
-            qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps, impl=impl)
+        view = pool.with_view(block_tbl, positions, page_ids, page_off,
+                              layer)
+        if cfg.mla:
+            out, pool = A.paged_mla_block(layer_p["mixer"], h, view,
+                                          mla_dims(cfg), window=w,
+                                          impl=impl)
+        else:
+            out, pool = A.paged_decode_attention_block(
+                layer_p["mixer"], h, view,
+                n_heads=cfg.q_heads, n_kv_heads=cfg.n_kv_heads,
+                head_dim=cfg.head_dim_, rope_theta=cfg.rope_theta,
+                window=w, qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps,
+                impl=impl)
         x = x + out
         x, _ = _ffn(layer_p, cfg, x, moe_impl)
         return (x, pool.pool()), None
 
+    # the leading dense layers are the pool's first layers
+    layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    nd = cfg.n_dense_layers
+    carry = (x, kv)
+    if nd:
+        carry, _ = jax.lax.scan(
+            body, carry,
+            (params["dense_layers"], windows[:nd], layer_ids[:nd]),
+            unroll=unroll)
     (x, new_kv), _ = jax.lax.scan(
-        body, (x, kv),
-        (params["layers"], windows, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        body, carry, (params["layers"], windows[nd:], layer_ids[nd:]),
         unroll=unroll)
     if all_positions:
         logits = lm_head(params, x, cfg)  # [B, C, Vp]
@@ -629,7 +691,15 @@ def decode_step(params, state, tokens, cfg: ArchConfig, *,
     so serving loops never sync a [B, Vp] logits plane to host just to
     pick a token (the device-resident batcher and ``ServeEngine.generate``
     both build on this).
+
+    Latent-attention (MLA) stacks and stacks with leading dense layers
+    decode through the paged pool only (``paged_decode_step``).
     """
+    if cfg.mla or cfg.n_dense_layers:
+        raise NotImplementedError(
+            f"{cfg.name}: the dense ring cache holds per-head K/V of one "
+            "homogeneous layer stack; latent attention and leading dense "
+            "layers decode through the paged pool (paged_decode_step)")
     pos = state["pos"]
     x = params["embed"][tokens].astype(COMPUTE_DTYPE)
     new_state: Params = {"pos": pos + 1}

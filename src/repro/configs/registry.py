@@ -19,7 +19,7 @@ ARCH_IDS: List[str] = [
     "xlstm_125m",
     "seamless_m4t_large_v2",
     "recurrentgemma_9b",
-    "moonshot_v1_16b_a3b",
+    "moonlight_16b_a3b",
     "qwen2_moe_a2_7b",
     "internvl2_2b",
 ]

@@ -90,6 +90,10 @@ def make_pipeline_step(cfg: ArchConfig, mesh, pspecs, *,
     schedule over ``n_micro`` microbatches and ``staged_specs`` mirrors
     the staged parameter tree (feed to ``NamedSharding``/``jax.jit``).
     """
+    if cfg.n_dense_layers:
+        raise NotImplementedError(
+            "pipeline step does not support leading dense layers yet: "
+            "they are a stack of their own beside the staged layers")
     if cfg.family in ("vlm", "encdec") or cfg.block_pattern:
         # vlm needs the patch frontend prepended / sliced, encdec needs
         # the encoder + cross-attention path, and block_pattern stacks

@@ -12,8 +12,11 @@ enc-dec, VLM):
   output dim;
 * MoE expert stacks ``[E, D, F]`` shard the expert dim over ``model``
   (expert parallelism; ``E`` is padded to a multiple of 16);
-* stacked-layer leading dims (``layers``/``macros``/``enc_layers``/
-  ``cross_layers``) are scan axes and never shard;
+* latent attention's up-projection ``w_kvb`` shards its heads like
+  ``wq``; its down-projection ``w_kva`` (one latent for every head)
+  replicates;
+* stacked-layer leading dims (``layers``/``dense_layers``/``macros``/
+  ``enc_layers``/``cross_layers``) are scan axes and never shard;
 * every proposal is validated against the mesh: an axis that does not
   divide the dim is dropped (replicated), so specs are safe for any mesh
   from the 1×2 CPU smoke mesh to the 16×16 production pod.
@@ -35,13 +38,14 @@ from jax.sharding import PartitionSpec as P
 MODEL_AXIS = "model"
 
 # roots whose first array dim is a lax.scan layer stack (never sharded)
-_STACKED_ROOTS = ("layers", "macros", "enc_layers", "cross_layers")
+_STACKED_ROOTS = ("layers", "dense_layers", "macros", "enc_layers",
+                  "cross_layers")
 
 # output-dim ("column") parallel projections: shard the last dim
 _COL_PARALLEL = {
     "wq", "wk", "wv", "w_gate", "w_up", "w_lin", "w_rec_gate", "w_in_gate",
     "w_i", "w_f", "w_gates", "r_gates", "router", "conv", "frontend_proj",
-    "embed_proj",
+    "embed_proj", "w_kvb",
 }
 # input-dim ("row") parallel projections: shard the first dim
 _ROW_PARALLEL = {"wo", "w_down", "w_out"}

@@ -31,8 +31,8 @@ and one KV group per call, f32 accumulation as the TPU requires), so
 the f32 sums may be ordered differently.  The kernel agrees with the
 oracle to a stated tolerance: a few f32 ulps in interpret mode
 (``tests/test_kernels.py``), about one bf16 step per call compiled for
-the TPU (``chip_smoke.py``).  Online
-softmax, which would bound VMEM at ``O(page)``, is later work.
+the TPU (``chip_smoke.py``).  Scores are divided by ``sqrt(hd)``, or
+by ``1 / scale`` where a softmax scale is given.
 
 Masking goes through ``attn_backend.mask_from_diff`` — the helper the
 jnp oracle and the dense decode path reach through ``position_mask`` —
@@ -41,6 +41,20 @@ between implementations.
 
 Decode is the ``C=1`` case of the prefill-chunk ``[B, C]`` variant;
 one kernel serves both (the chunk width only changes block shapes).
+
+A **latent pool** (multi-head latent attention: ``v_pages`` None) holds
+one ``[c | k_pe]`` row per token that is the key of every head, and
+whose first ``v_lanes`` lanes are the value; its queries are the
+absorbed ``[B, C, H, lanes]``.  The pool is read once per page, with no
+second copy for V, as one KV group of all ``H`` heads.  A slot's view
+of such rows does not fit VMEM at long contexts (8,192 positions of 576
+lanes are 9.4 MB, and 256 query rows' scores over them 8 MB of f32), so
+this case attends block by block with an online softmax: each grid step
+stages its ``per_step`` pages, folds their scores into a running max,
+sum and ``[rows, v_lanes]`` f32 accumulator, and the slot's last step
+writes ``acc / sum``.  Pages past the slot's last position are neither
+fetched (their index map repeats the last page, and Pallas skips the
+copy of an unchanged block) nor attended (their step is skipped).
 
 Exposed through the ``repro.nn.attn_backend`` registry as
 ``"pallas"``; ``interpret=None`` auto-selects interpret mode off-TPU
@@ -64,7 +78,7 @@ __all__ = ["paged_attention", "paged_attention_hbm_bytes"]
 
 def _kernel(n_ps: int, page: int, n_kv: int, hd: int, group: int,
             n_chunk: int, quantized: bool, out_dtype, per_step: int,
-            tbl_ref, pos_ref, win_ref, layer_ref, q_ref, *rest):
+            div: float, tbl_ref, pos_ref, win_ref, layer_ref, q_ref, *rest):
     """One grid step ``(b, s)``: stage slot b's logical pages
     ``s*per_step ..`` ``(s+1)*per_step - 1`` into the slot's VMEM view;
     on the slot's last step, attend for slot b.
@@ -115,13 +129,12 @@ def _kernel(n_ps: int, page: int, n_kv: int, hd: int, group: int,
             q_pos = jnp.where(r >= c * group, pos_ref[b * n_chunk + c], q_pos)
         k_pos = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         mask = mask_from_diff(q_pos - k_pos, win_ref[0], causal=True)
-        scale = np.float32(np.sqrt(hd))
         for h in range(n_kv):
             cols = pl.ds(h * hd, hd)
             sc = jax.lax.dot_general(
                 q_ref[0, h], kg[:, cols], (((1,), (1,)), ((), ())),
                 preferred_element_type=f32)
-            sc = sc.astype(out_dtype).astype(f32) / scale + mask
+            sc = sc.astype(out_dtype).astype(f32) / div + mask
             probs = jax.nn.softmax(sc, axis=-1).astype(out_dtype)
             out = jax.lax.dot_general(
                 probs, vg[:, cols], (((1,), (0,)), ((), ())),
@@ -129,11 +142,114 @@ def _kernel(n_ps: int, page: int, n_kv: int, hd: int, group: int,
             out_ref[0, h] = out.astype(out_dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+def _latent_kernel(n_ps: int, page: int, n_chunk: int, group: int,
+                   v_lanes: int, div: float, out_dtype, per_step: int,
+                   tbl_ref, pos_ref, win_ref, layer_ref, q_ref, *rest):
+    """One grid step ``(b, s)`` over a latent pool: attend slot b's
+    queries ``q_ref [1, 1, C*group, lanes]`` over its logical pages
+    ``s*per_step ..`` ``(s+1)*per_step - 1`` (``rest`` starts with
+    their ``per_step`` refs ``[1, page, lanes]``), folding them into the
+    running max ``m``, sum ``l`` and accumulator ``acc``; the slot's
+    last step writes the output."""
+    del tbl_ref, layer_ref
+    pages, (out_ref, kb, m_ref, l_ref, acc_ref) = (rest[:per_step],
+                                                   rest[per_step:])
+    b = pl.program_id(0)
+    s = pl.program_id(1)
+    f32 = jnp.float32
+    n_rows, T = n_chunk * group, per_step * page
+    first = s * T
+
+    @pl.when(s == 0)
+    def _init():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, f32)
+        l_ref[...] = jnp.zeros(l_ref.shape, f32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+    # a block past the slot's last query position is wholly masked
+    @pl.when(first <= pos_ref[b * n_chunk + n_chunk - 1])
+    def _block():
+        for j in range(per_step):
+            kb[pl.ds(j * page, page), :] = pages[j][0].astype(out_dtype)
+        shape = (n_rows, T)
+        r = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        q_pos = jnp.full(shape, pos_ref[b * n_chunk], jnp.int32)
+        for c in range(1, n_chunk):
+            q_pos = jnp.where(r >= c * group, pos_ref[b * n_chunk + c], q_pos)
+        k_pos = first + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        mask = mask_from_diff(q_pos - k_pos, win_ref[0], causal=True)
+        sc = jax.lax.dot_general(
+            q_ref[0, 0], kb[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=f32)
+        sc = sc.astype(out_dtype).astype(f32) / div + mask
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(out_dtype), kb[:, pl.ds(0, v_lanes)],
+            (((1,), (0,)), ((), ())), preferred_element_type=f32)
+        m_ref[...] = m_new
+
+    @pl.when(s == n_ps // per_step - 1)
+    def _out():
+        out_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(out_dtype)
+
+
+def _paged_latent(q, pool, gtbl, pos, win, lyr, *, n_ps, scale, v_lanes,
+                  interpret):
+    """The latent-pool case of ``paged_attention`` (its docstring)."""
+    B, C, H, lanes = q.shape
+    _, _, page, _ = pool.shape
+    dt = q.dtype
+    per_step = max(m for m in (8, 4, 2, 1) if n_ps % m == 0)
+
+    def page_map(j):
+        def index(b, s, tbl, pos, _win, lyr):
+            # pages past the slot's last position repeat its last page
+            last = jnp.clip(pos[b * C + C - 1] // page, 0, n_ps - 1)
+            return (lyr[0], tbl[b * n_ps + jnp.minimum(s * per_step + j,
+                                                       last)], 0, 0)
+        return index
+
+    def slot_map(b, s, *_):
+        return (b, 0, 0, 0)
+
+    R = C * H
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,  # gtbl, pos, win, layer
+        grid=(B, n_ps // per_step),
+        in_specs=[pl.BlockSpec((1, 1, R, lanes), slot_map)] + [
+            pl.BlockSpec((None, 1, page, lanes), page_map(j))
+            for j in range(per_step)],
+        out_specs=pl.BlockSpec((1, 1, R, v_lanes), slot_map),
+        scratch_shapes=[pltpu.VMEM((per_step * page, lanes), dt),  # block
+                        pltpu.VMEM((R, 1), jnp.float32),  # running max
+                        pltpu.VMEM((R, 1), jnp.float32),  # running sum
+                        pltpu.VMEM((R, v_lanes), jnp.float32)],  # acc
+    )
+    kern = functools.partial(_latent_kernel, n_ps, page, C, H, v_lanes,
+                             np.float32(1.0 / scale), dt, per_step)
+    # row c*H + h is chunk position c, head h: one KV group of H heads
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, 1, R, v_lanes), dt),
+        interpret=interpret,
+    )(gtbl, pos, win, lyr, q.reshape(B, 1, R, lanes), *[pool] * per_step)
+    return out.reshape(B, C, H, v_lanes)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "v_lanes",
+                                             "interpret"))
+def paged_attention(q: jax.Array, k_pages: jax.Array,
+                    v_pages: Optional[jax.Array],
                     block_tbl: jax.Array, positions: jax.Array, window,
                     layer, *, k_scale: Optional[jax.Array] = None,
                     v_scale: Optional[jax.Array] = None,
+                    scale: Optional[float] = None,
+                    v_lanes: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Attend ``q [B, C, H, hd]`` over one layer of a stacked paged pool
     through its block table.  Matches the registered ``"jnp"`` backend
@@ -156,6 +272,10 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         layer scan), hence passed as a scalar-prefetch operand.
       layer: which layer of the stack to read — traced in the layer
         scan, hence a scalar-prefetch operand too.
+      scale: the softmax scale (``None``: ``hd ** -0.5``).
+      v_lanes: with ``v_pages`` None, the pool is a latent pool whose
+        rows' first ``v_lanes`` lanes are the values, and the output is
+        ``[B, C, H, v_lanes]``.
       interpret: force Pallas interpret mode; ``None`` auto-selects it
         off-TPU (CPU CI runs this exact kernel interpreted).
     """
@@ -173,6 +293,10 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     pos = positions.astype(jnp.int32).reshape(-1)
     win = jnp.asarray(window, jnp.int32).reshape(1)
     lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+    if v_pages is None:
+        return _paged_latent(q, k_pages, gtbl, pos, win, lyr, n_ps=n_ps,
+                             scale=hd ** -0.5 if scale is None else scale,
+                             v_lanes=v_lanes, interpret=interpret)
     # query head kv*group + g (repeat_kv's order) -> [B, KV, C*group, hd]
     qg = q.reshape(B, C, KV, group, hd).transpose(0, 2, 1, 3, 4).reshape(
         B, KV, C * group, hd)
@@ -206,8 +330,9 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         scratch_shapes=[pltpu.VMEM((n_ps * page, KV * hd), dt),  # slot K
                         pltpu.VMEM((n_ps * page, KV * hd), dt)],  # slot V
     )
+    div = np.float32(np.sqrt(hd) if scale is None else 1.0 / scale)
     kern = functools.partial(_kernel, n_ps, page, KV, hd, group, C,
-                             quantized, dt, per_step)
+                             quantized, dt, per_step, div)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,

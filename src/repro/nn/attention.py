@@ -11,7 +11,12 @@ One implementation serves every assigned arch:
 * training/prefill use *blocked* attention (lax.scan over query blocks)
   so the S×S score matrix never materializes — the memory-roofline
   requirement for the 4k/32k shapes;
-* decode is a functional cache update + single-row attention.
+* decode is a functional cache update + single-row attention;
+* multi-head latent attention (MLA, DeepSeek-V2/V3) projects K and V
+  from one latent per token plus a rotary key shared by the heads: the
+  full-sequence path computes it in its published form, and the paged
+  path caches only ``[latent | rotary key]`` rows and attends in the
+  absorbed form (``paged_mla_block``).
 """
 from __future__ import annotations
 
@@ -97,6 +102,7 @@ def attend_blocked(q: jax.Array, k: jax.Array, v: jax.Array,
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
         q_pos = jnp.pad(q_pos, ((0, 0), (0, pad)))
     nblk = (Sq + pad) // qb
+    dv = v.shape[-1]
     qt = q.reshape(B, nblk, qb, H, hd).transpose(1, 0, 2, 3, 4)
     pt = q_pos.reshape(B, nblk, qb).transpose(1, 0, 2)
     kT = k.transpose(0, 2, 3, 1)  # [B,H,hd,Sk]
@@ -113,8 +119,8 @@ def attend_blocked(q: jax.Array, k: jax.Array, v: jax.Array,
         return None, o
 
     _, out = jax.lax.scan(body, None, (qt, pt))
-    out = out.transpose(1, 0, 2, 3, 4).reshape(B, nblk * qb, H, hd)
-    return out[:, :Sq].reshape(B, Sq, H * hd)
+    out = out.transpose(1, 0, 2, 3, 4).reshape(B, nblk * qb, H, dv)
+    return out[:, :Sq].reshape(B, Sq, H * dv)
 
 
 def attention_block(
@@ -184,7 +190,8 @@ def quantize_kv_int8(t: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q.astype(jnp.int8), scale
 
 
-def _paged_write(kv: PagedKV, k: jax.Array, v: jax.Array) -> PagedKV:
+def _paged_write(kv: PagedKV, k: jax.Array,
+                 v: Optional[jax.Array]) -> PagedKV:
     """Scatter a chunk's projected K/V into their physical pages.
 
     Shared verbatim by every attention backend — the write half is not
@@ -195,7 +202,9 @@ def _paged_write(kv: PagedKV, k: jax.Array, v: jax.Array) -> PagedKV:
     the ``B * C`` rows move), or ``[page_ids, page_off]`` of a
     per-layer one.  Out-of-range ``page_ids`` drop the write (padded
     chunk slots).  The int8 pool quantizes per token vector and
-    scatters the f32 scale planes alongside the values.
+    scatters the f32 scale planes alongside the values.  A latent pool
+    (MLA) has no V pool: ``k`` is the ``[B, C, lanes]`` row of each
+    token and ``v`` is None.
     """
     B, C = k.shape[:2]
     at = kv.index(kv.page_ids, kv.page_off)
@@ -211,6 +220,8 @@ def _paged_write(kv: PagedKV, k: jax.Array, v: jax.Array) -> PagedKV:
             return dataclasses.replace(
                 kv, k=put(kv.k, kq), v=put(kv.v, vq),
                 k_scale=put(kv.k_scale, ks), v_scale=put(kv.v_scale, vs))
+        if kv.latent:
+            return dataclasses.replace(kv, k=put(kv.k, k))
         return dataclasses.replace(kv, k=put(kv.k, k), v=put(kv.v, v))
 
 
@@ -387,3 +398,143 @@ def decode_attention_block(
             out = jnp.einsum("bhqs,bshd->bqhd", probs, vf).reshape(
                 B, 1, n_heads * head_dim)
     return out @ p["wo"].astype(x.dtype), cache_k, cache_v, new_scales
+
+
+# --------------------------------------------------------------------
+# multi-head latent attention
+# --------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class MLA:
+    """Widths of multi-head latent attention: ``heads`` query heads, a
+    latent of ``rank`` per token, ``nope`` + ``rope`` dims of each query
+    and key head (the rotary key of ``rope`` dims is one for all heads)
+    and ``v`` dims of each value head."""
+
+    heads: int
+    rank: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
+    eps: float
+
+    @property
+    def scale(self) -> float:
+        """Softmax scale: over the ``nope + rope`` dims of a score."""
+        return (self.nope + self.rope) ** -0.5
+
+    @property
+    def lanes(self) -> int:
+        """Lanes of a token's ``[c | k_pe]`` row."""
+        return self.rank + self.rope
+
+
+def init_mla(key, d_model: int, m: MLA) -> Dict:
+    """``wq [D, H*(nope+rope)]`` (per head ``[nope | rope]``), ``w_kva
+    [D, rank+rope]``, the latent's norm gain ``kv_norm [rank]``, ``w_kvb
+    [rank, H*(nope+v)]`` (per head ``[k_nope | v]``), ``wo [H*v, D]``."""
+    k = split_keys(key, 4)
+    return {
+        "wq": dense_init(k[0], (d_model, m.heads * (m.nope + m.rope))),
+        "w_kva": dense_init(k[1], (d_model, m.lanes)),
+        "kv_norm": jnp.zeros((m.rank,), jnp.float32),
+        "w_kvb": dense_init(k[2], (m.rank, m.heads * (m.nope + m.v))),
+        "wo": dense_init(k[3], (m.heads * m.v, d_model)),
+    }
+
+
+def mla_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding of MLA's ``rope`` dims ``x [B, S, H, rope]``
+    (DeepSeek's pairing): the interleaved dims are first gathered into
+    ``[evens | odds]``, then rotated in halves."""
+    B, S, H, d = x.shape
+    x = x.reshape(B, S, H, d // 2, 2).swapaxes(-1, -2).reshape(B, S, H, d)
+    return apply_rope(x, positions, theta)
+
+
+def _mla_q(p: Dict, x: jax.Array, m: MLA, positions: jax.Array):
+    """Per-head ``q_nope [B, S, H, nope]`` and rotated ``q_pe [B, S, H,
+    rope]``."""
+    B, S, _ = x.shape
+    q = (x @ p["wq"].astype(x.dtype)).reshape(B, S, m.heads,
+                                              m.nope + m.rope)
+    with jax.named_scope("mla"):
+        return q[..., :m.nope], mla_rope(q[..., m.nope:], positions,
+                                         m.theta)
+
+
+def _mla_latent(p: Dict, x: jax.Array, m: MLA, positions: jax.Array):
+    """The normed latent ``c [B, S, rank]`` and the rotated shared key
+    ``k_pe [B, S, rope]`` of each token."""
+    with jax.named_scope("mla"):
+        kv_a = x @ p["w_kva"].astype(x.dtype)
+        c = rms_norm(kv_a[..., :m.rank], p["kv_norm"], m.eps)
+        k_pe = mla_rope(kv_a[..., None, m.rank:], positions, m.theta)
+        return c, k_pe[:, :, 0]
+
+
+def _mla_up(p: Dict, m: MLA, dt):
+    """``w_kvb`` per head: ``W_UK [rank, H, nope]``, ``W_UV [rank, H,
+    v]``."""
+    w = p["w_kvb"].astype(dt).reshape(m.rank, m.heads, m.nope + m.v)
+    return w[..., :m.nope], w[..., m.nope:]
+
+
+def mla_block(p: Dict, x: jax.Array, m: MLA, *,
+              positions: Optional[jax.Array] = None, window=0,
+              q_block: int = DEFAULT_Q_BLOCK) -> jax.Array:
+    """Causal MLA over a whole sequence in its published form: each
+    token's per-head ``[k_nope | v]`` up-projected from its latent, keys
+    ``[k_nope | k_pe]``, scores over ``nope + rope`` dims."""
+    B, S, _ = x.shape
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+    q_nope, q_pe = _mla_q(p, x, m, positions)
+    c, k_pe = _mla_latent(p, x, m, positions)
+    w_uk, w_uv = _mla_up(p, m, x.dtype)
+    k_nope = jnp.einsum("bsr,rhn->bshn", c, w_uk)
+    v = jnp.einsum("bsr,rhv->bshv", c, w_uv)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe[:, :, None], (B, S, m.heads, m.rope))],
+        axis=-1)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    out = attend_blocked(q, k, v, positions, positions, window,
+                         q_block=q_block)
+    return out @ p["wo"].astype(x.dtype)
+
+
+def paged_mla_block(p: Dict, x: jax.Array, kv: PagedKV, m: MLA, *,
+                    window, impl: str = "jnp") -> Tuple[jax.Array, PagedKV]:
+    """MLA through the paged latent pool, in the absorbed form.
+
+    ``kv`` is a latent :class:`~repro.nn.attn_backend.PagedKV` (one pool
+    of ``[c | k_pe]`` rows, ``rank + rope`` lanes or more, the rest
+    zeros; no V pool) with its view attached, as
+    ``paged_decode_attention_block`` takes it.  The chunk's rows are
+    written in place, then each head's query is
+    absorbed into the latent space, ``q_lat_h = [q_nope_h W_UK_h^T |
+    q_pe_h]``, so every head reads the same rows: the scores
+    ``q_lat_h . [c | k_pe]`` equal the published ``q_h . k_h``, the
+    value is the first ``rank`` lanes of the row, and each head's output
+    leaves the latent space through ``W_UV_h``."""
+    B, C, _ = x.shape
+    lanes = kv.k.shape[-1]  # the row, padded past rank + rope with zeros
+    q_nope, q_pe = _mla_q(p, x, m, kv.pos)
+    c, k_pe = _mla_latent(p, x, m, kv.pos)
+    pad = jnp.zeros((B, C, lanes - m.lanes), x.dtype)
+    kv = _paged_write(kv, jnp.concatenate([c, k_pe, pad], axis=-1), None)
+    w_uk, w_uv = _mla_up(p, m, x.dtype)
+    with jax.named_scope("mla"):
+        q = jnp.concatenate(
+            [jnp.einsum("bchn,rhn->bchr", q_nope, w_uk), q_pe,
+             jnp.broadcast_to(pad[:, :, None], (B, C, m.heads,
+                                                lanes - m.lanes))], axis=-1)
+    attend = AB.get(AB.resolve(impl))
+    with jax.named_scope("attention"):
+        o = attend(q, kv, n_heads=m.heads, head_dim=lanes, window=window,
+                   scale=m.scale, v_dim=m.rank)
+    with jax.named_scope("mla"):
+        o = jnp.einsum("bchr,rhv->bchv", o, w_uv)
+    return o.reshape(B, C, m.heads * m.v) @ p["wo"].astype(x.dtype), kv

@@ -14,9 +14,13 @@ implementation so they cannot drift apart:
   everywhere else (where the kernel still runs, in interpret mode, but
   only as a correctness vehicle, not a fast path).
 
-A backend is a callable ``fn(q, kv, *, n_heads, head_dim, window) ->
-[B, C, H, hd]`` that attends the already-projected queries over an
-already-written :class:`PagedKV` (pools updated, view fields set).  The
+A backend is a callable ``fn(q, kv, *, n_heads, head_dim, window,
+scale=None, v_dim=None) -> [B, C, H, hd]`` that attends the
+already-projected queries over an already-written :class:`PagedKV`
+(pools updated, view fields set).  ``scale`` is the softmax scale
+(``None``: ``head_dim ** -0.5``).  Over a latent pool (MLA: one pool of
+rows that are keys, whose first ``v_dim`` lanes are the values, for
+every head) the output is ``[B, C, H, v_dim]``.  The
 scatter/write half of the step is *not* part of the backend contract —
 it runs once in ``nn.attention.paged_decode_attention_block`` so the
 returned pools are bitwise identical no matter which backend attends.
@@ -109,6 +113,10 @@ class PagedKV:
       own, as tests build it; the kernel reads it as the one-layer
       stack.
 
+    A **latent** pool (multi-head latent attention) has ``v`` None:
+    ``k`` holds one ``[c | k_pe]`` row per token, the key of every
+    head, whose first lanes are the value.
+
     The per-call view is ``block_tbl [B, n_ps]`` (logical page ->
     physical page), ``pos [B, C]`` (absolute position per chunk slot),
     the precomputed scatter coordinates ``page_ids``/``page_off [B, C]``
@@ -122,7 +130,7 @@ class PagedKV:
     """
 
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array] = None
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
     block_tbl: Optional[jax.Array] = None
@@ -137,6 +145,12 @@ class PagedKV:
         property: None-ness is pytree structure, not data, so it is
         knowable at trace time."""
         return self.k_scale is not None
+
+    @property
+    def latent(self) -> bool:
+        """True for the latent pool (no V pool) — static, like
+        ``quantized``."""
+        return self.v is None
 
     @property
     def n_pages(self) -> int:
@@ -254,11 +268,14 @@ def _gathered_views(q: jax.Array, kv: PagedKV):
             x = x * scale[at].astype(dt).reshape(B, S, -1, 1)
         return x
 
+    if kv.latent:
+        return view(kv.k, None), None
     return view(kv.k, kv.k_scale), view(kv.v, kv.v_scale)
 
 
 def _attend_jnp(q: jax.Array, kv: PagedKV, *, n_heads: int, head_dim: int,
-                window) -> jax.Array:
+                window, scale: Optional[float] = None,
+                v_dim: Optional[int] = None) -> jax.Array:
     """The jnp oracle: gather the full logical view, mask on absolute
     positions, full-axis softmax.  Bitwise-reference semantics; every
     other backend is gated against this path."""
@@ -268,17 +285,19 @@ def _attend_jnp(q: jax.Array, kv: PagedKV, *, n_heads: int, head_dim: int,
     with jax.named_scope("kv"):
         kf, vf = _gathered_views(q, kv)
     kf = repeat_kv(kf, n_heads)
-    vf = repeat_kv(vf, n_heads)
+    vf = kf[..., :v_dim] if kv.latent else repeat_kv(vf, n_heads)
     k_pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     mask = position_mask(kv.pos, k_pos, window, causal=True)  # [B, C, S]
-    s = jnp.einsum("bqhd,bshd->bhqs", q, kf) / np.sqrt(head_dim)
+    div = np.sqrt(head_dim) if scale is None else 1.0 / scale
+    s = jnp.einsum("bqhd,bshd->bhqs", q, kf) / div
     s = s.astype(jnp.float32) + mask[:, None, :, :]
     probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqs,bshd->bqhd", probs, vf)
 
 
 def _attend_pallas(q: jax.Array, kv: PagedKV, *, n_heads: int,
-                   head_dim: int, window) -> jax.Array:
+                   head_dim: int, window, scale: Optional[float] = None,
+                   v_dim: Optional[int] = None) -> jax.Array:
     """The Pallas page-walking kernel (``kernels.paged_attention``),
     reading the call's layer of the stacked pool in place; a per-layer
     pool is the one-layer stack at layer 0.
@@ -295,7 +314,8 @@ def _attend_pallas(q: jax.Array, kv: PagedKV, *, n_heads: int,
         layer = 0
     k, v, k_scale, v_scale = pools
     return paged_attention(q, k, v, kv.block_tbl, kv.pos, window, layer,
-                           k_scale=k_scale, v_scale=v_scale)
+                           k_scale=k_scale, v_scale=v_scale, scale=scale,
+                           v_lanes=v_dim if kv.latent else None)
 
 
 register("jnp", _attend_jnp)

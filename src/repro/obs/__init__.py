@@ -20,7 +20,8 @@ attached Tracer are fed from those stamps, so the step and the host loop
 are the same code with a tracer or without, and token streams are
 bit-exact either way.  Beside these, the serve path marks itself for the
 JAX profiler: ``jax.named_scope`` names in the fused step (``gate``,
-``kv``, ``attention``, ``mlp`` / ``moe``, ``lm_head``, ``sample``) and
+``kv``, ``attention``, ``mla`` (latent attention's projections into and
+out of the latent space), ``mlp`` / ``moe``, ``lm_head``, ``sample``) and
 ``serve.*`` host spans in ``DeviceContinuousBatcher.run``.
 """
 from .metrics import Counter, Gauge, Histogram, Metrics

@@ -45,7 +45,7 @@ def test_forward_and_train_step(arch):
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
-def test_decode_matches_forward(arch):
+def test_decode_matches_forward(arch, monkeypatch):
     """Teacher-forced decode logits == full forward logits, step by step.
 
     This pins the entire serving path (cache insert, RoPE positions,
@@ -54,6 +54,13 @@ def test_decode_matches_forward(arch):
     cfg = get_smoke_config(arch)
     if cfg.family == "vlm":
         pytest.skip("vlm decode starts after a patch prefix; covered below")
+    if cfg.mla:
+        # both paths in float32: the paged path attends in the absorbed
+        # form and the forward in the published one, and in bfloat16
+        # their different rounding flips a near-tie of the sigmoid
+        # router's top-k (0.87 at one step of this seed), so the forms
+        # are pinned where only summation order tells them apart
+        monkeypatch.setattr(M, "COMPUTE_DTYPE", jnp.float32)
     params = M.init_params(cfg, jax.random.PRNGKey(1))
     B, S = 2, 12
     batch = _batch(cfg, B, S, seed=1)
@@ -62,9 +69,23 @@ def test_decode_matches_forward(arch):
     if cfg.family == "encdec":
         pytest.skip("encdec decode uses precomputed cross-KV; see "
                     "test_encdec_cross_consistency")
+    errs = []
+    if cfg.mla:
+        # latent attention decodes through the paged pool only (the
+        # dense ring decode_step refuses it): one token a step
+        page = 4
+        kv = M.init_paged_kv(cfg, B * S // page, page)
+        tbl = jnp.arange(B * S // page, dtype=jnp.int32).reshape(B, -1)
+        step = jax.jit(lambda p, kv, pos, t: M.paged_decode_step(
+            p, kv, tbl, pos, t, jnp.ones((B,), jnp.int32), cfg))
+        for t in range(S):
+            logits, kv = step(params, kv, jnp.full((B,), t, jnp.int32),
+                              batch["tokens"][:, t: t + 1])
+            errs.append(float(jnp.max(jnp.abs(logits - fwd[:, t, :]))))
+        assert max(errs) < 1e-4, errs  # f32 summation order (logits ~4)
+        return
     state = M.init_decode_state(cfg, B, S)
     step = jax.jit(lambda p, s, t: M.decode_step(p, s, t, cfg))
-    errs = []
     for t in range(S):
         logits, state = step(params, state, batch["tokens"][:, t: t + 1])
         errs.append(float(jnp.max(jnp.abs(

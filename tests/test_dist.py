@@ -287,9 +287,11 @@ def test_split_layers_for_stages_structure():
 
 
 def test_pipeline_refuses_frontend_families():
-    """vlm/encdec would silently train a token-only objective — refuse."""
+    """vlm/encdec would silently train a token-only objective, and a
+    stack with leading dense layers would stage without them — refuse."""
     mesh = _fake_mesh()
-    for arch in ("internvl2_2b", "seamless_m4t_large_v2"):
+    for arch in ("internvl2_2b", "seamless_m4t_large_v2",
+                 "moonlight_16b_a3b"):
         cfg = get_smoke_config(arch)
         with pytest.raises(NotImplementedError):
             PP.make_pipeline_step(cfg, mesh, {}, n_stages=1)

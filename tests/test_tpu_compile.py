@@ -211,3 +211,67 @@ def test_paged_step_keeps_the_pool_in_place(one_chip, on_tpu, kv_dtype):
     assert "tpu_custom_call" in hlo
     layer_cells = N * PAGE * cfg.n_kv_heads * cfg.head_dim_
     assert _pool_moves(hlo, N, layer_cells) == []
+
+
+@pytest.mark.parametrize("C", [1, 16])
+def test_latent_paged_attention_compiles_at_moonlight_widths(one_chip, C):
+    """The kernel on a latent pool at Moonlight's widths (16 heads, rows
+    of 576 lanes, values of 512) over 8,192 positions a slot: its online
+    softmax keeps VMEM within the scoped limit."""
+    H, lanes, r, n_ps = 16, 576, 512, 8192 // PAGE
+    args = [_spec((B, C, H, lanes), jnp.bfloat16, one_chip),
+            _spec((2, B * n_ps, PAGE, lanes), jnp.bfloat16, one_chip), None,
+            _spec((B, n_ps), jnp.int32, one_chip),
+            _spec((B, C), jnp.int32, one_chip),
+            _spec((), jnp.int32, one_chip),
+            _spec((), jnp.int32, one_chip)]
+    fn = jax.jit(functools.partial(paged_attention, scale=192 ** -0.5,
+                                   v_lanes=r, interpret=False))
+    hlo = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_latent_paged_step_writes_one_pool_in_place(one_chip, on_tpu):
+    """moonlight-16b-a3b at published widths, depth cut to the dense
+    layer and two MoE layers, through the paged step in a donated
+    ``while_loop`` carry: one latent pool (rows of 576 lanes padded to
+    640, so that it keeps the row-major layout the kernel reads on the
+    way in and out of the program), written by one in-place
+    scatter a layer scan (the dense stack's and the MoE stack's), read
+    by the kernel, with no copy or slice of it and no per-head K/V
+    pool anywhere in the program."""
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"), n_layers=3,
+                              expert_ranks=4)
+    N = B * PAGES_PER_SLOT
+    params = _shapes(jax.eval_shape(
+        functools.partial(M.init_params, cfg), jax.random.PRNGKey(0)),
+        one_chip)
+    pool = _shapes(jax.eval_shape(functools.partial(
+        M.init_paged_kv, cfg, N, PAGE)), one_chip)
+    assert pool.v is None and pool.k.shape == (3, N, PAGE, 640)
+    i32 = functools.partial(_spec, dtype=jnp.int32, sharding=one_chip)
+
+    def run_k(params, pool, tbl, pos, toks, n_new, k):
+        def body(c):
+            i, pool, toks = c
+            nxt, pool = M.paged_decode_step(
+                params, pool, tbl, pos + i, toks, n_new, cfg,
+                sample_greedy=True, attn_impl="auto")
+            return i + 1, pool, nxt[:, None]
+
+        _, pool, toks = jax.lax.while_loop(
+            lambda c: c[0] < k, body, (jnp.int32(0), pool, toks))
+        return pool, toks
+
+    hlo = jax.jit(run_k, donate_argnums=(1,)).lower(
+        params, pool, i32((B, PAGES_PER_SLOT)), i32((B,)), i32((B, 1)),
+        i32((B,)), i32(())).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert _pool_moves(hlo, N, N * PAGE * 640) == []
+    scatters = re.findall(r"= \w+\[([\d,]+)\]\S* scatter\(", hlo)
+    paged = [s for s in scatters if f"{N},{PAGE}," in s]
+    assert paged == [f"3,{N},{PAGE},640"] * 2, scatters
+    # nothing laid out as K or V of heads: every pool-sized buffer is
+    # the latent pool's rows, 576 lanes padded to 640
+    heads = re.findall(rf"\[(?:\d+,)*{N},{PAGE},(\d+)\]", hlo)
+    assert set(heads) == {"640"}, set(heads)
